@@ -15,8 +15,8 @@ backend unchanged:
                 the dense form of any fixed `SparseGraph` topology, OMD dual
                 step and crash freeze — in two Pallas kernels with per-node
                 parameter blocks resident in VMEM across the round. Runs
-                under ``interpret=True`` on CPU (CI validates the real
-                kernel bodies) and compiles to Mosaic on TPU.
+                under ``interpret=True`` on the CPU (the tests check the
+                real kernel bodies) and compiles to Mosaic on a TPU.
 
 The pallas backend keeps the engines' state pytrees (`SimState` /
 `GossipState`), their PRNG stream (noise is sampled OUTSIDE the kernels
@@ -30,14 +30,16 @@ Two execution modes, picked per spec (``backend_options={"mode": ...}``):
 
   fused  — mixing happens INSIDE the update kernel via the dense (m, m)
            matrix of the spec's fixed topology (any `SparseGraph` degree);
-           requires m <= ``max_fused_nodes`` (the dense block must sit in
-           VMEM next to the streamed operands).
+           the dense block must sit in VMEM next to the streamed operands
+           (`repro.kernels.round_fused.vmem_bytes`).
   hybrid — mixing stays in XLA (`mixer.mix` / `mix_history` — any mixer:
            faults, per-edge heterogeneous delays, time-varying schedules)
            between the stats kernel and a smaller fused dual-step kernel.
 
 ``mode="auto"`` (default) fuses when the resolved mixer lowers to a fixed
-sparse graph and m fits, else falls back to hybrid. The node-sharded path
+sparse graph and the update kernel fits the VMEM budget, else falls back
+to hybrid; a node count whose stats or dual-step kernel fits no block
+width raises ValueError before anything is lowered. The node-sharded path
 (`repro.api.shard_node`) always runs hybrid per shard: its ppermute halo
 exchange stays outside the kernels by design.
 
@@ -167,23 +169,51 @@ class PallasBackend:
     """Fused-kernel execution of the round body (see module docstring).
 
     mode:            "auto" | "fused" | "hybrid" (auto fuses when possible).
-    block_cols:      lanes per kernel grid step (the n-block width).
-    interpret:       None -> interpret off TPU (the CPU CI path); a bool
+    block_cols:      widest n-block per kernel grid step, in lanes; each
+                     kernel narrows it to fit `round_fused.VMEM_LIMIT_BYTES`.
+    interpret:       None -> interpret on the CPU platform only; a bool
                      pins it.
-    max_fused_nodes: dense-A cap for the fused mode; above it auto falls
-                     back to hybrid and "fused" raises.
     """
 
     mode: str = "auto"
     block_cols: int = 512
     interpret: bool | None = None
-    max_fused_nodes: int = 1024
     name: str = "pallas"
 
     def __post_init__(self):
         if self.mode not in ("auto", "fused", "hybrid"):
             raise ValueError(f"unknown pallas mode {self.mode!r}; expected "
                              "'auto', 'fused' or 'hybrid'")
+
+    # -- mode selection ------------------------------------------------------
+
+    def _dense_plan(self, spec, mixer):
+        """The dense mixing form (A, diag, delay) when the round fuses, None
+        when it runs hybrid. Raises ValueError when a kernel the chosen mode
+        needs fits no block width under the VMEM budget — before anything
+        is lowered."""
+        rf = _round_kernels()
+        m_pad, n_pad = rf._pad_rows(spec.nodes), rf._pad_cols(spec.dim)
+        rf.col_block("round_stats", m_pad, n_pad, self.block_cols)
+        if self.mode != "hybrid":
+            dense = _dense_mix_form(spec, mixer)
+            if dense is None and self.mode == "fused":
+                raise ValueError(
+                    f"backend='pallas' mode='fused' needs a fixed topology "
+                    f"(got mixer={type(mixer).__name__}); use mode='hybrid' "
+                    f"or 'auto'")
+            if dense is not None:
+                try:
+                    rf.col_block("round_update", m_pad, n_pad,
+                                 self.block_cols)
+                    return dense
+                except ValueError as err:
+                    if self.mode == "fused":
+                        raise ValueError(
+                            f"backend='pallas' mode='fused': {err}; use "
+                            f"mode='hybrid' or 'auto'") from None
+        rf.col_block("dual_step", m_pad, n_pad, self.block_cols)
+        return None
 
     # -- unsharded chunk program --------------------------------------------
 
@@ -222,17 +252,7 @@ class PallasBackend:
         clip_norm = clip.max_norm if isinstance(clip, PerNodeL2Clipper) \
             else None
 
-        dense = None
-        if self.mode != "hybrid":
-            dense = _dense_mix_form(spec, mixer)
-            if dense is not None and dense[0].shape[0] > self.max_fused_nodes:
-                dense = None
-            if dense is None and self.mode == "fused":
-                raise ValueError(
-                    f"backend='pallas' mode='fused' needs a fixed topology "
-                    f"with nodes <= {self.max_fused_nodes} (got mixer="
-                    f"{type(mixer).__name__}, m={m}); use mode='hybrid' or "
-                    f"'auto'")
+        dense = self._dense_plan(spec, mixer)
         if dense is not None:
             A, diag_v, delay = dense
             A_pad = _pad2(A, m_pad, m_pad)
@@ -256,7 +276,8 @@ class PallasBackend:
                 factor = jnp.minimum(1.0, clip_norm
                                      / jnp.maximum(gnorm, 1e-12))
             coeff = -(active * y) * factor
-            wb_loss = jnp.mean(jnp.maximum(1.0 - y * wbdot, 0.0))
+            wb_loss = rf.node_sum(jnp.maximum(1.0 - y * wbdot, 0.0),
+                                  interpret=interpret) / m
             # zero COUNT first (small ints are exact in f32), then divide —
             # bit-equal to the reference's mean-of-indicators
             sparsity = (m * n - jnp.sum(nnz)) / (m * n)
@@ -340,6 +361,8 @@ class PallasBackend:
         m, n = part.m, spec.dim
         block, m_pad_g = part.block, part.m_pad
         blk_pad, n_pad = rf._pad_rows(block), rf._pad_cols(n)
+        for kernel in ("round_stats", "dual_step"):
+            rf.col_block(kernel, blk_pad, n_pad, self.block_cols)
         interpret = _interpret(self.interpret)
         mech = spec.resolve_mechanism()
         rule = spec.resolve_local_rule()
@@ -444,9 +467,7 @@ def _reference() -> ReferenceBackend:
 
 @BACKENDS.register("pallas")
 def _pallas(mode: str = "auto", block_cols: int = 512,
-            interpret: bool | None = None,
-            max_fused_nodes: int = 1024) -> PallasBackend:
+            interpret: bool | None = None) -> PallasBackend:
     """Fused Pallas round body (see docs/kernels.md)."""
     return PallasBackend(mode=mode, block_cols=block_cols,
-                         interpret=interpret,
-                         max_fused_nodes=max_fused_nodes)
+                         interpret=interpret)

@@ -715,7 +715,9 @@ def run_batch(spec: RunSpec, seeds, engine: str = "sim", *,
     every trajectory, checkpoint and aggregate. Seeds are independent private
     runs, so the sharded results stay bit-identical to the single-device
     vmap (and to sequential `run()`) — noise, delay rings and resume
-    included. ``devices="auto"`` uses `jax.local_device_count()` and falls
+    included. On a TPU that holds for backend="pallas"; XLA may order the
+    reference backend's reductions differently for S seeds on one device
+    than for S/D per device, a last-bit difference (docs/sweeps.md). ``devices="auto"`` uses `jax.local_device_count()` and falls
     back to plain vmap on a 1-device host.
 
     ``node_devices=`` composes node sharding with the seed batch into a 2-D
@@ -814,17 +816,16 @@ def run_batch(spec: RunSpec, seeds, engine: str = "sim", *,
             sharding = None
             chunk_jit = jax.jit(jax.vmap(chunk_fn))
         else:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import NamedSharding, PartitionSpec
             pspec = PartitionSpec("seed")
             sharding = NamedSharding(mesh, pspec)
             # each device runs the SAME vmapped chunk program over its S/D
             # block of seeds; no collectives cross the blocks, so per-seed
             # trajectories cannot differ from the single-device vmap
-            chunk_jit = jax.jit(shard_map(
+            chunk_jit = jax.jit(jax.shard_map(
                 jax.vmap(chunk_fn), mesh=mesh,
                 in_specs=(pspec, pspec, pspec), out_specs=(pspec, pspec),
-                check_rep=False))
+                check_vma=False))
 
     def _place(tree):
         """Pad the seed axis to S + pad and lay it out over the mesh."""

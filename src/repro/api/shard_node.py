@@ -353,7 +353,6 @@ def make_node_chunk_fn(spec: RunSpec, engine: str, mesh,
     and every spec gains a leading "seed" dim (the ("seed","node") grid
     `run_batch` uses).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.algorithm1 import RoundOutput
 
@@ -415,10 +414,10 @@ def make_node_chunk_fn(spec: RunSpec, engine: str, mesh,
     data_spec = P(*lead, None, "node")
     outs_spec = RoundOutput(loss=data_spec, w_bar_loss=P(*lead),
                             sparsity=P(*lead), correct=data_spec)
-    smapped = shard_map(body, mesh=mesh,
-                        in_specs=(state_spec, data_spec, data_spec),
-                        out_specs=(state_spec, outs_spec),
-                        check_rep=False)
+    smapped = jax.shard_map(body, mesh=mesh,
+                            in_specs=(state_spec, data_spec, data_spec),
+                            out_specs=(state_spec, outs_spec),
+                            check_vma=False)
 
     def chunk_fn(state, xs, ys):
         state = _pad_state(state, pad)

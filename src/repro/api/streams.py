@@ -52,7 +52,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.api.registry import STREAMS
-from repro.data.social import SocialStream, labels_from_logits, round_keys
+from repro.data.social import (LABEL_PRECISION, SocialStream,
+                               labels_from_logits, round_keys)
 
 __all__ = [
     "Stream",
@@ -144,7 +145,8 @@ class DriftStream:
             lambda k: jax.random.normal(k, (self.nodes, self.n))
         )(kx) / jnp.sqrt(self.n)
         W = jax.vmap(self.w_true_at)(jnp.arange(t0, t1))       # (T, n)
-        y = labels_from_logits(jnp.einsum("tn,tmn->tm", W, x))
+        y = labels_from_logits(jnp.einsum("tn,tmn->tm", W, x,
+                                          precision=LABEL_PRECISION))
         if self.label_noise > 0:
             flip = jax.vmap(
                 lambda k: jax.random.uniform(k, (self.nodes,))
@@ -203,7 +205,8 @@ class HeterogeneousStream:
         x = jax.vmap(
             lambda k: jax.random.normal(k, (self.nodes, self.n))
         )(kx) * scales[None, :, None] / jnp.sqrt(self.n)
-        y = labels_from_logits(jnp.einsum("n,tmn->tm", w, x))
+        y = labels_from_logits(jnp.einsum("n,tmn->tm", w, x,
+                                          precision=LABEL_PRECISION))
         flip = jax.vmap(
             lambda k: jax.random.uniform(k, (self.nodes,))
         )(kn) < rates[None, :]
@@ -270,7 +273,8 @@ class BurstyStream:
             )(keys)
             total = total + jnp.where((k < c)[:, :, None], sample, 0.0)
         x = total / c[:, :, None] / jnp.sqrt(self.n)
-        y = labels_from_logits(jnp.einsum("n,tmn->tm", w, x))
+        y = labels_from_logits(jnp.einsum("n,tmn->tm", w, x,
+                                          precision=LABEL_PRECISION))
         return x.astype(jnp.float32), y.astype(jnp.float32)
 
     def chunks(self, chunk_rounds: int = 512):

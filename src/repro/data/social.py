@@ -26,6 +26,13 @@ import jax
 import jax.numpy as jnp
 
 
+# Labels are the sign of a float32 contraction. Pinning it to HIGHEST keeps
+# a stream's labels a function of its seed alone: at the ambient default a
+# TPU contracts in bf16 passes, and a logit near zero could then flip sign
+# between runs made under different matmul-precision settings.
+LABEL_PRECISION = jax.lax.Precision.HIGHEST
+
+
 def labels_from_logits(logits: jax.Array) -> jax.Array:
     """y = +1 iff <w*, x> >= 0 — an exact-zero logit maps to +1, never to
     the invalid label 0 (jnp.sign(0) == 0 would silently break the hinge
@@ -73,7 +80,7 @@ class SocialStream:
         x = jax.vmap(
             lambda k: jax.random.normal(k, (self.nodes, self.n))
         )(kx) / jnp.sqrt(self.n)
-        logits = jnp.einsum("n,tmn->tm", w, x)
+        logits = jnp.einsum("n,tmn->tm", w, x, precision=LABEL_PRECISION)
         y = labels_from_logits(logits)
         if self.label_noise > 0:
             flip = jax.vmap(

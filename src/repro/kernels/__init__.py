@@ -8,12 +8,14 @@ kernels fuse it into streamed passes over the (m, n) parameter block (see
 `ref` holds the pure-jnp oracles every kernel is allclose-tested against.
 
 The kernels are reached through `RunSpec(backend="pallas")` — see
-`repro.api.backends`; on CPU they run with ``interpret=True`` so CI
-validates the real kernel bodies.
+`repro.api.backends`; on the CPU they run with ``interpret=True`` so the
+tests check the real kernel bodies.
 """
-from repro.kernels.round_fused import (DEFAULT_BLOCK_COLS, LANE,
-                                       MAX_FUSED_NODES, SUBLANE, dual_step,
-                                       round_stats, round_update)
+from repro.kernels.round_fused import (DEFAULT_BLOCK_COLS, LANE, SUBLANE,
+                                       VMEM_LIMIT_BYTES, col_block, dual_step,
+                                       node_sum, round_stats, round_update,
+                                       vmem_bytes)
 
-__all__ = ["round_stats", "round_update", "dual_step", "LANE", "SUBLANE",
-           "DEFAULT_BLOCK_COLS", "MAX_FUSED_NODES"]
+__all__ = ["round_stats", "round_update", "dual_step", "node_sum", "LANE",
+           "SUBLANE", "DEFAULT_BLOCK_COLS", "VMEM_LIMIT_BYTES", "col_block",
+           "vmem_bytes"]

@@ -1,7 +1,7 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` so every
-test validates the actual kernel body; on TPU they compile to Mosaic. The
+On the CPU the kernels execute with ``interpret=True`` so every test checks
+the actual kernel body; on a TPU they compile to Mosaic. The
 wrappers also handle padding/reshaping from arbitrary parameter pytrees to
 the kernels' (rows, 128) tiled layout.
 """
@@ -22,7 +22,10 @@ SUBLANE = _pdomd.SUBLANE
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret only on the CPU; any other platform compiles the kernels,
+    so a device that cannot run them fails instead of silently
+    interpreting."""
+    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------------------------

@@ -40,19 +40,25 @@ the whole pass, and applies the unified mixing algebra of
                                                into coeff)
     next  = alive ? next : theta              (fault crash freeze)
 
+``node_sum`` reduces the (m,) w_bar hinge terms in one kernel, so the
+cross-node mean rounds the same under any seed batch.
+
 Unfused, the round body is ~7 HBM round-trips over the (m, n) state; fused
 it is 3 reads + 1 write for the update pass plus the stats pass — the
 memory-bound win `repro.obs.cost` rooflines in BENCH_kernels.json.
 
 Tiling: n is zero-padded to a LANE (128) multiple and the grid walks
-column blocks of ``block_cols`` lanes; m is zero-padded to a SUBLANE (8)
-multiple and stays fully resident (the dense A cap — `MAX_FUSED_NODES` —
-bounds VMEM). Zero-padded rows/columns are provably inert: w and x are
+column blocks of up to ``block_cols`` lanes; m is zero-padded to a SUBLANE
+(8) multiple and stays fully resident. Each kernel's block width comes
+from `col_block`, which holds the kernel's scoped VMEM (`vmem_bytes`:
+double-buffered streamed blocks, body temporaries, the resident dense A)
+under `VMEM_LIMIT_BYTES`; a shape no width can fit raises ValueError
+before lowering. Zero-padded rows/columns are provably inert: w and x are
 zero there, so every reduction and the update leave them zero. The TPU
 grid is sequential, so pass 1 accumulates its reductions into re-visited
 output blocks (`@pl.when(j == 0)` zero-init, as in `kernels/hinge_grad`).
-On CPU the kernels run with ``interpret=True`` — CI validates the real
-kernel bodies.
+On the CPU the kernels run with ``interpret=True``, so tests check the
+real kernel bodies.
 """
 from __future__ import annotations
 
@@ -61,14 +67,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 SUBLANE = 8
 DEFAULT_BLOCK_COLS = 512
-# dense A is (m_pad, m_pad) f32 resident across the column grid; 1024^2 * 4B
-# = 4 MiB, leaving ~12 MiB of VMEM for the streamed (m_pad, block_cols)
-# operands. Larger m falls back to the hybrid path (mix stays in XLA).
-MAX_FUSED_NODES = 1024
+# The scoped-VMEM limit Mosaic gives one kernel by default on a TPU v5e. It
+# is passed to every pallas_call explicitly, so the budget that `col_block`
+# checks and the limit the compiler enforces are the same number.
+VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+KERNELS = ("round_stats", "round_update", "dual_step")
 
 
 def _pad_cols(n: int) -> int:
@@ -79,13 +87,54 @@ def _pad_rows(m: int) -> int:
     return -(-m // SUBLANE) * SUBLANE
 
 
-def _col_block(n_pad: int, block_cols: int) -> int:
-    """Largest LANE multiple <= block_cols that divides n_pad."""
-    b = min(block_cols, n_pad)
-    b -= b % LANE
-    while n_pad % b:
+def vmem_bytes(kernel: str, m_pad: int, block: int) -> int:
+    """Upper bound on one kernel's scoped VMEM at an (m_pad, block) grid step.
+
+    ``blk`` is one streamed f32 (m_pad, block) tile; the Pallas pipeline
+    double-buffers every streamed operand, and the kernel body keeps a few
+    block-sized temporaries. ``row`` is the (m_pad, 4) per-node table, which
+    VMEM pads to 128 lanes. The coefficients bound what the v5e compiler
+    accepts under VMEM_LIMIT_BYTES, found by compiling each kernel at
+    blocks of 128..2048 lanes and every m_pad the budget admits (steps of
+    8 rows for round_update, 16 for the others); the boundary cases are
+    tests/test_tpu_compile.py:
+
+      round_stats   2 streamed inputs x 2 buffers + temporaries  -> 8 blk
+      dual_step     3 inputs + 1 output x 2 buffers + temps      -> 10 blk
+      round_update  4 inputs + 2 outputs x 2 buffers + temps     -> 21 blk,
+                    plus the resident A (lane-padded) and the float32
+                    matmul's scratch, which grows with A times the block
+                    width: a * (2 * block / 128 - 1) in all
+    """
+    blk = m_pad * block * 4
+    row = m_pad * LANE * 4
+    if kernel == "round_stats":
+        return 8 * blk + 2 * row
+    if kernel == "dual_step":
+        return 10 * blk + 2 * row
+    if kernel == "round_update":
+        a = m_pad * _pad_cols(m_pad) * 4
+        return a * (2 * block // LANE - 1) + 21 * blk + 2 * row
+    raise ValueError(f"unknown kernel {kernel!r}; expected one of {KERNELS}")
+
+
+def col_block(kernel: str, m_pad: int, n_pad: int, block_cols: int) -> int:
+    """Widest LANE multiple <= block_cols that divides n_pad and keeps
+    ``kernel`` within VMEM_LIMIT_BYTES; ValueError when none does."""
+    b = max(LANE, min(block_cols, n_pad) // LANE * LANE)
+    while b >= LANE:
+        if n_pad % b == 0 and vmem_bytes(kernel, m_pad, b) <= VMEM_LIMIT_BYTES:
+            return b
         b -= LANE
-    return b
+    need = vmem_bytes(kernel, m_pad, LANE)
+    raise ValueError(
+        f"{kernel} at (m_pad={m_pad}, n_pad={n_pad}) needs "
+        f"{need / 2**20:.2f} MiB of VMEM even at {LANE}-lane blocks, over "
+        f"the {VMEM_LIMIT_BYTES / 2**20:.0f} MiB scoped VMEM limit "
+        f"(VMEM_LIMIT_BYTES); fewer nodes per device fit")
+
+
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 # ---------------------------------------------------------------------------
@@ -142,7 +191,7 @@ def round_stats(theta: jax.Array, x: jax.Array, lam_t: jax.Array,
     if n_pad % LANE or m_pad % SUBLANE:
         raise ValueError(f"round_stats needs (8k, 128k) padded input, got "
                          f"{theta.shape}")
-    B = _col_block(n_pad, block_cols)
+    B = col_block("round_stats", m_pad, n_pad, block_cols)
     grid = (n_pad // B,)
     blk = pl.BlockSpec((m_pad, B), lambda j: (0, j))
     red = pl.BlockSpec((m_pad, LANE), lambda j: (0, 0))
@@ -159,6 +208,7 @@ def round_stats(theta: jax.Array, x: jax.Array, lam_t: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((m_pad, LANE), jnp.float32)] * 4
         + [jax.ShapeDtypeStruct((SUBLANE, n_pad), jnp.float32)],
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(theta.astype(jnp.float32), x.astype(jnp.float32), scal)
     return dot[:, 0], xsq[:, 0], nnz[:, 0], wbdot[:, 0], wsum[0]
 
@@ -182,7 +232,9 @@ def _update_kernel(a_ref, theta_ref, delta_ref, x_ref, recv_ref,
     tilde = theta + delta_ref[...]
     recv = jnp.where(use_recv > 0, recv_ref[...], tilde)
     s = jnp.where(noise_self > 0, tilde, theta)
-    mixed = jnp.dot(a_ref[...], recv,
+    # HIGHEST pins Mosaic to a float32 contraction; its default may take
+    # bf16 passes, which would break the float32 contract of docs/kernels.md
+    mixed = jnp.dot(a_ref[...], recv, precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32) + diag * (s - recv)
     nxt = mixed - alpha * (coeff * x_ref[...])
     out_ref[...] = jnp.where(alive > 0, nxt, theta)
@@ -210,7 +262,7 @@ def round_update(A: jax.Array, theta: jax.Array, delta: jax.Array,
                          f"{theta.shape}")
     if A.shape != (m_pad, m_pad):
         raise ValueError(f"A must be ({m_pad}, {m_pad}), got {A.shape}")
-    B = _col_block(n_pad, block_cols)
+    B = col_block("round_update", m_pad, n_pad, block_cols)
     grid = (n_pad // B,)
     blk = pl.BlockSpec((m_pad, B), lambda j: (0, j))
     pernode = jnp.stack([
@@ -231,10 +283,41 @@ def round_update(A: jax.Array, theta: jax.Array, delta: jax.Array,
         out_specs=[blk, blk],
         out_shape=[jax.ShapeDtypeStruct((m_pad, n_pad), jnp.float32)] * 2,
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(A.astype(jnp.float32), theta.astype(jnp.float32),
       delta.astype(jnp.float32), x.astype(jnp.float32),
       recv.astype(jnp.float32), pernode, scal)
     return theta_next, tilde
+
+
+# ---------------------------------------------------------------------------
+# cross-node sum in one fixed order
+# ---------------------------------------------------------------------------
+
+def _sum_kernel(v_ref, out_ref):
+    out_ref[...] = jnp.broadcast_to(jnp.sum(v_ref[...]), out_ref.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def node_sum(v: jax.Array, *, interpret: bool = False) -> jax.Array:
+    """Sum of an (m,) per-node vector, reduced inside one kernel.
+
+    XLA orders a reduction by the shape it sees, so the same (m,) sum can
+    round differently in a vmap over S seeds on one device and over S/D
+    seeds per device. The kernel reduces each vector the same way under
+    any batch, which keeps `run_batch`'s seed-sharded and vmapped results
+    equal to the bit on a TPU.
+    """
+    m = v.shape[0]
+    width = -(-m // (SUBLANE * LANE)) * SUBLANE * LANE
+    tile = jnp.pad(v.astype(jnp.float32), (0, width - m)).reshape(SUBLANE, -1)
+    out = pl.pallas_call(
+        _sum_kernel,
+        out_shape=jax.ShapeDtypeStruct((SUBLANE, LANE), jnp.float32),
+        interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
+    )(tile)
+    return out[0, 0]
 
 
 def _dual_kernel(mixed_ref, x_ref, theta_ref, pernode_ref, scal_ref, out_ref):
@@ -256,7 +339,7 @@ def dual_step(mixed: jax.Array, x: jax.Array, theta: jax.Array,
     if n_pad % LANE or m_pad % SUBLANE:
         raise ValueError(f"dual_step needs (8k, 128k) padded input, got "
                          f"{mixed.shape}")
-    B = _col_block(n_pad, block_cols)
+    B = col_block("dual_step", m_pad, n_pad, block_cols)
     grid = (n_pad // B,)
     blk = pl.BlockSpec((m_pad, B), lambda j: (0, j))
     pernode = jnp.stack([
@@ -274,5 +357,6 @@ def dual_step(mixed: jax.Array, x: jax.Array, theta: jax.Array,
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), jnp.float32),
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(mixed.astype(jnp.float32), x.astype(jnp.float32),
       theta.astype(jnp.float32), pernode, scal)

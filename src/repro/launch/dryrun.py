@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.launch import hlo_analysis, hlo_cost, steps
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, gossip_nodes, gossip_axes
 from repro.models import build_model
 from repro.models.config import INPUT_SHAPES
@@ -367,6 +368,7 @@ def main() -> int:
     ap.add_argument("--dim", type=int, default=256)
     ap.add_argument("--chunk-rounds", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.stream:
         from repro.launch.train import parse_stream_options

@@ -3,18 +3,12 @@ never touches jax device state."""
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after jax 0.4.x; older jax only has Auto semantics
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` with Auto axis types when the installed jax has them."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis of type Auto."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,6 +24,7 @@ import json
 import time
 
 from repro.api.spec import RunSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import BurstyReplay, ServeConfig, ServeService
 
 __all__ = ["serve_social", "demo_refusal", "main"]
@@ -128,6 +129,7 @@ def main(argv=None):
                     help="small spec + refusal demo; exercises every "
                          "acceptance path on CPU in seconds")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         summary = serve_social(

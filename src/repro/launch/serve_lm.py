@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.launch import steps
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 
 
@@ -76,6 +77,7 @@ def main():
     ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--full", dest="smoke", action="store_false")
     args = ap.parse_args()
+    enable_compile_cache()
     serve(args.arch, batch=args.batch, prompt_len=args.prompt_len, gen=args.gen,
           cache_len=args.cache_len, smoke=args.smoke)
 

@@ -21,6 +21,7 @@ import json
 from typing import Any
 
 from repro.api import RunSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sweep import DEFAULT_STORE, SweepSpec, SweepStoreMiss, sweep
 
 
@@ -115,6 +116,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: list[str] | None = None) -> dict:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     axes = dict(parse_axis(a) for a in args.axis)
     base = RunSpec(
         nodes=args.nodes, dim=args.dim, horizon=args.horizon, eps=args.eps,

@@ -32,6 +32,7 @@ from repro.api.runner import run as api_run
 from repro.configs import ARCH_IDS, get_config
 from repro.data.lm import lm_batches
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 
 
@@ -184,6 +185,7 @@ def main():
     ap.add_argument("--full", dest="smoke", action="store_false")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if not args.arch and not args.stream:
         ap.error("one of --arch or --stream is required")
     train(args.arch, strategy=args.strategy, nodes=args.nodes, steps=args.steps,

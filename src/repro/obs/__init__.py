@@ -98,14 +98,12 @@ class Telemetry:
 
     def profile(self):
         """Context manager capturing a ``jax.profiler`` device trace into
-        ``profile_dir`` (no-op when unset or the profiler is unavailable)."""
+        ``profile_dir`` (no-op when unset). A profiler that fails raises:
+        a run asked to trace never silently runs untraced."""
         if not self.profile_dir:
             return contextlib.nullcontext()
         import jax
-        try:
-            return jax.profiler.trace(self.profile_dir)
-        except Exception:                    # pragma: no cover - no profiler
-            return contextlib.nullcontext()
+        return jax.profiler.trace(self.profile_dir)
 
     @staticmethod
     def new_run_id() -> str:

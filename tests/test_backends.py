@@ -32,8 +32,8 @@ from repro.api import BACKENDS, ExecConfig, PallasBackend, RunSpec, run
 from repro.api.backends import pallas_supported
 from repro.api.registry import UnknownEntryError
 from repro.api.runner import run_batch
-from repro.kernels.round_fused import (dual_step, round_stats, round_update,
-                                       _pad_cols, _pad_rows)
+from repro.kernels.round_fused import (dual_step, node_sum, round_stats,
+                                       round_update, _pad_cols, _pad_rows)
 
 ATOL = 5e-6      # float32 reduction-order bound for float trajectories
 EXACT = ("correct", "sparsity", "eps_ledger")
@@ -269,6 +269,16 @@ def test_dual_step_odd_shapes(m, n):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("m", [6, 64, 1100])
+def test_node_sum_matches_sum(m):
+    v = jax.random.uniform(jax.random.PRNGKey(m), (m,))
+    got = node_sum(v, interpret=True)
+    np.testing.assert_allclose(float(got), float(jnp.sum(v)), rtol=1e-6)
+    batch = jax.vmap(lambda u: node_sum(u, interpret=True))(
+        jnp.stack([v, 2 * v]))
+    assert float(batch[0]) == float(got)
+
+
 def test_round_stats_rejects_unpadded():
     with pytest.raises(ValueError, match="padded"):
         round_stats(jnp.zeros((3, 40)), jnp.zeros((3, 40)),
@@ -322,3 +332,31 @@ for engine in ("sim", "dist"):
                          text=True, timeout=520, env=env)
     assert out.returncode == 0, out.stderr[-3000:]
     assert out.stdout.count("OK") == 2
+
+
+# -- mode selection under the VMEM budget ------------------------------------
+
+@pytest.mark.parametrize("nodes,mode,want", [
+    (64, "auto", "fused"),        # §V: A is 16 KiB
+    (1024, "auto", "fused"),      # the update kernel fits at 128 lanes
+    (1100, "auto", "hybrid"),     # A no longer fits beside the streams
+    (1100, "hybrid", "hybrid"),
+])
+def test_auto_mode_follows_vmem_budget(nodes, mode, want):
+    spec = RunSpec(nodes=nodes, dim=10_000, horizon=4, backend="pallas")
+    assert _mode(PallasBackend(mode=mode), spec) == want
+
+
+def _mode(backend, spec):
+    plan = backend._dense_plan(spec, spec.resolve_mixer())
+    return "hybrid" if plan is None else "fused"
+
+
+def test_budget_refusals_name_the_limit():
+    spec = RunSpec(nodes=1100, dim=10_000, horizon=4, backend="pallas")
+    with pytest.raises(ValueError, match="scoped VMEM limit"):
+        _mode(PallasBackend(mode="fused"), spec)
+    with pytest.raises(ValueError, match="scoped VMEM limit"):
+        _mode(PallasBackend(), spec.replace(nodes=4096))
+    faulty = _spec(faults="links", faults_options={"link_rate": 0.1})
+    assert _mode(PallasBackend(), faulty) == "hybrid"
