@@ -381,9 +381,14 @@ def run(spec: RunSpec | None, engine: str = "sim", *,
 
     ``obs=`` takes a `repro.obs.Telemetry` (default: the ambient
     ``repro.obs.active()``, disabled unless ``repro.obs.enable()`` ran).
-    When enabled, the runner wraps compile / chunk / checkpoint / regret
-    phases in spans, publishes ``run.rounds`` / ``run.chunk_seconds`` /
-    ``run.eps_total`` (and fault connectivity) into the metrics registry,
+    Each chunk-loop iteration runs in phase spans (``run.stream``,
+    ``run.chunk`` holding ``run.dispatch`` and ``run.wait``,
+    ``run.account``, ``run.fetch``, ``run.log``, ``run.checkpoint``,
+    ``run.on_chunk``); each span is also a ``jax.profiler``
+    annotation, which a running profiler records whether or not telemetry
+    is enabled (see `repro.obs.trace`). When enabled, the runner also keeps
+    the compile / chunk-loop / regret spans in memory, publishes
+    ``run.rounds`` / ``run.chunk_seconds`` / ``run.eps_total`` (and fault connectivity) into the metrics registry,
     streams ``run_start`` / ``chunk`` / ``checkpoint`` / ``run_end`` events,
     and — with ``Telemetry(cost=True)`` — records the predicted-vs-measured
     chunk cost under ``result.metrics['obs']['cost']``. Telemetry is strictly
@@ -475,61 +480,74 @@ def run(spec: RunSpec | None, engine: str = "sim", *,
     done_to = start
     t0 = time.time()
     with tel.profile():
+        # every phase of an iteration sits in a span (and so in a profiler
+        # annotation), so a device-idle gap between two chunk programs can
+        # be laid to what the host was doing in it
         for a, b in zip(bounds[:-1], bounds[1:]):
-            if a == bounds[0] and first_chunk is not None:
-                xs, ys = first_chunk   # don't regenerate the warmup chunk
-            else:
-                xs, ys = stream.chunk(a, b)
+            with tel.span("run.stream"):
+                if a == bounds[0] and first_chunk is not None:
+                    xs, ys = first_chunk   # don't regenerate the warmup chunk
+                else:
+                    xs, ys = stream.chunk(a, b)
             with tel.span("run.chunk", round_start=a, round_end=b) as sp:
-                eng_state, outs = chunk_jit(eng_state, xs, ys)
+                with tel.span("run.dispatch"):
+                    eng_state, outs = chunk_jit(eng_state, xs, ys)
                 # block on the STATE too, not just the metric outputs — the
                 # timed region must cover the whole round computation, and
                 # on_chunk consumers (snapshot publication) need a finished
                 # state
-                jax.block_until_ready((eng_state, outs))
-            if fault_sched is not None and fault_sched.has_crashes:
-                # crashed rounds release no noised broadcast — don't charge
-                # them
-                accountant.step(b - a,
-                                participation=fault_sched.participation(a, b))
-            else:
-                accountant.step(b - a)
-            done_to = b
-            if tel.enabled:
-                secs = sp.duration_s
-                eps_now = accountant.guarantee_at(b)
-                tel.metrics.counter("run.rounds").inc(b - a)
-                tel.metrics.histogram("run.chunk_seconds").observe(secs)
-                tel.metrics.gauge("run.eps_total").set(eps_now)
-                if chunk_cost is not None:
-                    chunk_cost.record(secs)
-                tel.emit("chunk", run_id=run_id, round_start=a, round_end=b,
-                         seconds=secs,
-                         rounds_per_sec=((b - a) / secs if secs > 0 else None),
-                         eps=eps_now)
-            losses.append(np.asarray(outs.loss))
-            wb_losses.append(np.asarray(outs.w_bar_loss))
-            sparsities.append(np.asarray(outs.sparsity))
-            corrects.append(np.asarray(outs.correct))
-            if cfg.compute_regret:
-                xs_all.append(np.asarray(xs))
-                ys_all.append(np.asarray(ys))
+                with tel.span("run.wait"):
+                    jax.block_until_ready((eng_state, outs))
+            with tel.span("run.account"):
+                if fault_sched is not None and fault_sched.has_crashes:
+                    # crashed rounds release no noised broadcast — don't
+                    # charge them
+                    accountant.step(
+                        b - a, participation=fault_sched.participation(a, b))
+                else:
+                    accountant.step(b - a)
+                done_to = b
+                if tel.enabled:
+                    secs = sp.duration_s
+                    eps_now = accountant.guarantee_at(b)
+                    tel.metrics.counter("run.rounds").inc(b - a)
+                    tel.metrics.histogram("run.chunk_seconds").observe(secs)
+                    tel.metrics.gauge("run.eps_total").set(eps_now)
+                    if chunk_cost is not None:
+                        chunk_cost.record(secs)
+                    tel.emit("chunk", run_id=run_id, round_start=a,
+                             round_end=b, seconds=secs,
+                             rounds_per_sec=((b - a) / secs if secs > 0
+                                             else None),
+                             eps=eps_now)
+            with tel.span("run.fetch"):
+                losses.append(np.asarray(outs.loss))
+                wb_losses.append(np.asarray(outs.w_bar_loss))
+                sparsities.append(np.asarray(outs.sparsity))
+                corrects.append(np.asarray(outs.correct))
+                if cfg.compute_regret:
+                    xs_all.append(np.asarray(xs))
+                    ys_all.append(np.asarray(ys))
             if logger:
-                for i, t in enumerate(range(a, b)):
-                    logger.log(t, {
-                        "loss": float(losses[-1][i].mean()),
-                        "w_bar_loss": float(wb_losses[-1][i]),
-                        "sparsity": float(sparsities[-1][i]),
-                        "accuracy": float(corrects[-1][i].mean()),
-                        "eps": accountant.guarantee_at(t + 1),
-                    })
+                with tel.span("run.log"):
+                    for i, t in enumerate(range(a, b)):
+                        logger.log(t, {
+                            "loss": float(losses[-1][i].mean()),
+                            "w_bar_loss": float(wb_losses[-1][i]),
+                            "sparsity": float(sparsities[-1][i]),
+                            "accuracy": float(corrects[-1][i].mean()),
+                            "eps": accountant.guarantee_at(t + 1),
+                        })
             if (cfg.checkpoint_every and cfg.checkpoint_dir
                     and b % cfg.checkpoint_every == 0):
                 with tel.span("run.checkpoint", step=b):
                     save_checkpoint(cfg.checkpoint_dir, b, eng_state)
-                tel.emit("checkpoint", run_id=run_id, step=b)
-            if on_chunk is not None and on_chunk(b, eng_state, accountant):
-                break
+                    tel.emit("checkpoint", run_id=run_id, step=b)
+            if on_chunk is not None:
+                with tel.span("run.on_chunk"):
+                    stop = on_chunk(b, eng_state, accountant)
+                if stop:
+                    break
     wall = time.time() - t0
     T = done_to                 # < requested horizon iff on_chunk stopped early
     if logger:
@@ -882,50 +900,59 @@ def run_batch(spec: RunSpec, seeds, engine: str = "sim", *,
     xs_all, ys_all = [], []
     t0 = time.time()
     with tel.profile():
+        # the same phase spans as run()'s loop, under run_batch.* names
         for a, b in zip(bounds[:-1], bounds[1:]):
-            if a == bounds[0] and first_chunk is not None:
-                xs, ys = first_chunk
-            else:
-                xs, ys = stacked_chunk(a, b)
+            with tel.span("run_batch.stream"):
+                if a == bounds[0] and first_chunk is not None:
+                    xs, ys = first_chunk
+                else:
+                    xs, ys = stacked_chunk(a, b)
             with tel.span("run_batch.chunk", round_start=a, round_end=b,
                           seeds=S) as sp:
-                eng_state, outs = chunk_jit(eng_state, xs, ys)
+                with tel.span("run_batch.dispatch"):
+                    eng_state, outs = chunk_jit(eng_state, xs, ys)
                 # block on state + outputs so the timed region measures the
                 # whole round computation, not just the dispatch of the
                 # metric arrays
-                jax.block_until_ready((eng_state, outs))
-            if fault_sched is not None and fault_sched.has_crashes:
-                accountant.step(b - a,
-                                participation=fault_sched.participation(a, b))
-            else:
-                accountant.step(b - a)
-            if tel.enabled:
-                secs = sp.duration_s
-                eps_now = accountant.guarantee_at(b)
-                tel.metrics.counter("run_batch.rounds").inc(b - a)
-                tel.metrics.histogram("run_batch.chunk_seconds").observe(secs)
-                tel.metrics.gauge("run_batch.eps_total").set(eps_now)
-                if chunk_cost is not None:
-                    chunk_cost.record(secs)
-                tel.emit("chunk", run_id=run_id, round_start=a, round_end=b,
-                         seconds=secs,
-                         rounds_per_sec=((b - a) / secs if secs > 0 else None),
-                         eps=eps_now)
-            # [:S] masks the pad seeds (duplicates of the last real seed) out
-            # of every recorded trajectory; a no-op on the unsharded path
-            losses.append(np.asarray(outs.loss)[:S])           # (S, C, m)
-            wb_losses.append(np.asarray(outs.w_bar_loss)[:S])  # (S, C)
-            sparsities.append(np.asarray(outs.sparsity)[:S])
-            corrects.append(np.asarray(outs.correct)[:S])
-            if cfg.compute_regret:
-                xs_all.append(np.asarray(xs)[:S])
-                ys_all.append(np.asarray(ys)[:S])
+                with tel.span("run_batch.wait"):
+                    jax.block_until_ready((eng_state, outs))
+            with tel.span("run_batch.account"):
+                if fault_sched is not None and fault_sched.has_crashes:
+                    accountant.step(
+                        b - a, participation=fault_sched.participation(a, b))
+                else:
+                    accountant.step(b - a)
+                if tel.enabled:
+                    secs = sp.duration_s
+                    eps_now = accountant.guarantee_at(b)
+                    tel.metrics.counter("run_batch.rounds").inc(b - a)
+                    tel.metrics.histogram(
+                        "run_batch.chunk_seconds").observe(secs)
+                    tel.metrics.gauge("run_batch.eps_total").set(eps_now)
+                    if chunk_cost is not None:
+                        chunk_cost.record(secs)
+                    tel.emit("chunk", run_id=run_id, round_start=a,
+                             round_end=b, seconds=secs,
+                             rounds_per_sec=((b - a) / secs if secs > 0
+                                             else None),
+                             eps=eps_now)
+            with tel.span("run_batch.fetch"):
+                # [:S] masks the pad seeds (duplicates of the last real
+                # seed) out of every recorded trajectory; a no-op on the
+                # unsharded path
+                losses.append(np.asarray(outs.loss)[:S])           # (S, C, m)
+                wb_losses.append(np.asarray(outs.w_bar_loss)[:S])  # (S, C)
+                sparsities.append(np.asarray(outs.sparsity)[:S])
+                corrects.append(np.asarray(outs.correct)[:S])
+                if cfg.compute_regret:
+                    xs_all.append(np.asarray(xs)[:S])
+                    ys_all.append(np.asarray(ys)[:S])
             if (cfg.checkpoint_every and cfg.checkpoint_dir
                     and b % cfg.checkpoint_every == 0):
                 with tel.span("run_batch.checkpoint", step=b):
                     save_checkpoint(cfg.checkpoint_dir, b,
                                     _unpad_tree(eng_state, S))
-                tel.emit("checkpoint", run_id=run_id, step=b)
+                    tel.emit("checkpoint", run_id=run_id, step=b)
     wall = time.time() - t0
     eng_state = _unpad_tree(eng_state, S)
 

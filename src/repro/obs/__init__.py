@@ -4,7 +4,9 @@ One :class:`Telemetry` object bundles the four observability primitives the
 stack publishes into:
 
   * a span :class:`~repro.obs.trace.Tracer` (compile/chunk/checkpoint/
-    publish phases, Chrome ``trace.json`` export);
+    publish phases, Chrome ``trace.json`` export; every span, enabled or
+    not, is also a ``jax.profiler`` annotation that a running profiler
+    records);
   * a thread-safe :class:`~repro.obs.metrics.MetricsRegistry` (eps burn,
     rounds/sec, serve counters, fault connectivity);
   * an optional JSONL :class:`~repro.obs.events.EventLog` run-event stream

@@ -1,4 +1,4 @@
-"""Host-side span tracer: nested, thread-safe, Chrome-trace exportable.
+"""Span tracer with two sinks: process memory and the JAX profiler's trace.
 
 Every phase of a run — compile, chunk execution, checkpoint write, snapshot
 publication — is wrapped in a :class:`Span` so "where did the wall-clock
@@ -7,10 +7,19 @@ stack (a chunk span inside a run span keeps its parent), carry arbitrary
 JSON-able attributes, and export to the Chrome/Perfetto ``trace.json``
 format (``chrome://tracing``, https://ui.perfetto.dev).
 
+Every span also writes a ``jax.profiler.TraceAnnotation`` of the same
+name, so it lands on the profiler's host plane, on the device trace's
+clock, whenever a profiler is running (``jax.profiler.trace``,
+``Telemetry(profile_dir=...)``); the annotation's event name is the bare
+span name and the span's attributes arrive as its stats. The running
+profiler is the only switch for that record: with none running an
+annotation costs about a microsecond and records nothing.
+
 The tracer is a pure host-side observer: it never touches device values,
 so a traced run is bit-identical to an untraced one (the ``obs_off_identical``
-gate in BENCH_obs.json holds telemetry to that). A disabled tracer hands
-out a shared no-op span — the hot loop pays one attribute check.
+gate in BENCH_obs.json holds telemetry to that). A disabled tracer keeps
+nothing in memory: its spans are annotations only, with no attributes, so
+the off path builds no strings.
 
 >>> tracer = Tracer()
 >>> with tracer.span("run", engine="sim"):
@@ -26,10 +35,10 @@ out a shared no-op span — the hot loop pays one attribute check.
 >>> tracer.summary()["chunk"]["count"]
 3
 >>> off = Tracer(enabled=False)
->>> with off.span("never"):
+>>> with off.span("never") as sp:
 ...     pass
->>> off.spans
-[]
+>>> off.spans, sp.duration_s
+([], 0.0)
 """
 from __future__ import annotations
 
@@ -38,6 +47,18 @@ import threading
 import time
 
 __all__ = ["Span", "Tracer", "NULL_SPAN"]
+
+_annotation = None          # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``; jax is imported on the first span,
+    not with this module."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 class Span:
@@ -83,8 +104,28 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _AnnotationSpan:
+    """A disabled tracer's span: the profiler annotation alone, no args,
+    nothing kept in memory; entering it yields `NULL_SPAN`."""
+
+    __slots__ = ("_name", "_ann")
+
+    def __init__(self, name: str):
+        self._name = name
+
+    def __enter__(self) -> _NullSpan:
+        # a TraceAnnotation starts when it is made, so make it here
+        self._ann = _trace_annotation()(self._name)
+        self._ann.__enter__()
+        return NULL_SPAN
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        return False
+
+
 class _SpanCtx:
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_ann")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
@@ -96,12 +137,15 @@ class _SpanCtx:
         span.parent = stack[-1].name if stack else None
         span.depth = len(stack)
         stack.append(span)
+        self._ann = _trace_annotation()(span.name, **span.args)
+        self._ann.__enter__()
         span.t0 = time.perf_counter()
         return span
 
     def __exit__(self, *exc) -> bool:
         span = self._span
         span.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         self._tracer._stack().pop()
         self._tracer._record(span)
         return False
@@ -142,10 +186,12 @@ class Tracer:
             self.spans.append(span)
 
     def span(self, name: str, **args):
-        """Context manager timing one region; yields the live :class:`Span`
-        (a shared no-op when the tracer is disabled)."""
+        """Context manager timing one region and writing a profiler
+        annotation of the same name; yields the live :class:`Span` (the
+        shared no-op `NULL_SPAN`, with ``duration_s == 0.0``, when the tracer
+        is disabled: then only the annotation is written, without ``args``)."""
         if not self.enabled:
-            return NULL_SPAN
+            return _AnnotationSpan(name)
         return _SpanCtx(self, Span(name, args=args))
 
     def clear(self) -> None:

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 import repro.obs as obs
-from repro.api import RunSpec, run, run_batch
+from repro.api import ExecConfig, RunSpec, run, run_batch
 from repro.launch.obs import main as obs_main
 from repro.launch.obs import summarize_events
 from repro.obs import (EventLog, MetricsRegistry, Telemetry, Tracer,
@@ -283,6 +283,131 @@ def test_ambient_telemetry_reaches_run():
     res = run(_spec(), chunk_rounds=4, warmup=False)
     assert res.metrics["obs"]["run_id"]
     assert tel.metrics.snapshot()["run.rounds"] == 8
+
+
+# -- profiler annotations ----------------------------------------------------
+
+# the chunk-loop phases of each driver, in loop order (run() with a log,
+# checkpoints and an on_chunk hook; run_batch has neither log nor hook)
+PHASES = {
+    "run": ("stream", "chunk", "dispatch", "wait", "account", "fetch",
+            "log", "checkpoint", "on_chunk"),
+    "run_batch": ("stream", "chunk", "dispatch", "wait", "account", "fetch",
+                  "checkpoint"),
+}
+
+
+def _profiled(fn, trace_dir, prefixes):
+    """Call ``fn`` under ``jax.profiler.trace`` and return its result and
+    the ``/host:CPU`` events of the trace whose names start with one of
+    ``prefixes``, as (name, start_ns, end_ns, stats), ordered by start (an
+    enclosing event before the events it holds)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(trace_dir)):
+        out = fn()
+    path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+              for plane in ProfileData.from_file(path).planes
+              if plane.name == "/host:CPU"
+              for line in plane.lines for e in line.events
+              if e.name.startswith(prefixes)]
+    return out, sorted(events, key=lambda e: (e[1], -e[2]))
+
+
+def _drive(driver, tmp_path, tel=None):
+    """3 chunks of ``driver`` with every optional phase switched on."""
+    cfg = ExecConfig(chunk_rounds=4, warmup=False, checkpoint_every=4,
+                     checkpoint_dir=str(tmp_path / "ckpt"), obs=tel)
+    if driver == "run":
+        return run(_spec(horizon=12),
+                   exec=cfg.replace(log_path=str(tmp_path / "log.csv")),
+                   on_chunk=lambda *_: False)
+    return run_batch(_spec(horizon=12), [0, 1], exec=cfg)
+
+
+def _loop_events(events, driver):
+    """The chunk-loop phase events of ``driver`` (its regret pass left
+    out)."""
+    names = {f"{driver}.{p}" for p in PHASES[driver]}
+    return [e for e in events if e[0] in names]
+
+
+def _assert_phases(loop, driver):
+    assert [e[0] for e in loop] == [f"{driver}.{p}"
+                                    for p in PHASES[driver]] * 3
+    chunks = [e for e in loop if e[0] == f"{driver}.chunk"]
+    for inner in ("dispatch", "wait"):
+        held = [e for e in loop if e[0] == f"{driver}.{inner}"]
+        assert all(c[1] <= h[1] and h[2] <= c[2]
+                   for c, h in zip(chunks, held)), inner
+    # the phases follow one another and do not overlap
+    top = [e for e in loop if e[0] not in
+           {f"{driver}.dispatch", f"{driver}.wait"}]
+    assert all(a[2] <= b[1] for a, b in zip(top, top[1:]))
+
+
+def test_span_writes_profiler_annotation(tmp_path):
+    on, off = Tracer(), Tracer(enabled=False)
+
+    def spans():
+        with on.span("phase", engine="sim"):
+            pass
+        with off.span("quiet", engine="sim") as sp:
+            pass
+        return sp
+
+    sp, events = _profiled(spans, tmp_path, ("phase", "quiet"))
+    by_name = {e[0]: e for e in events}
+    # bare names; an enabled span's attributes arrive as stats, a disabled
+    # one writes none and keeps nothing in memory
+    assert by_name["phase"][3] == {"engine": "sim"}
+    assert by_name["quiet"][3] == {}
+    assert [s.name for s in on.spans] == ["phase"]
+    assert off.spans == [] and sp.duration_s == 0.0
+
+
+@pytest.mark.parametrize("driver", ["run", "run_batch"])
+def test_chunk_loop_phases_on_profiler_clock(driver, tmp_path):
+    tel = Telemetry(events=str(tmp_path / "e.jsonl"))
+    _, events = _profiled(lambda: _drive(driver, tmp_path, tel),
+                          tmp_path / "trace", driver)
+    tel.close()
+    _assert_phases(_loop_events(events, driver), driver)
+    summary = tel.tracer.summary()
+    assert all(summary[f"{driver}.{p}"]["count"] == 3
+               for p in PHASES[driver])
+    chunk = [e for e in events if e[0] == f"{driver}.chunk"]
+    assert [e[3]["round_end"] for e in chunk] == [4, 8, 12]
+
+
+@pytest.mark.parametrize("driver", ["run", "run_batch"])
+def test_chunk_loop_phases_with_telemetry_off(driver, tmp_path):
+    tel = obs.active()
+    assert not tel.enabled
+    _, events = _profiled(lambda: _drive(driver, tmp_path),
+                          tmp_path / "trace", driver)
+    loop = _loop_events(events, driver)
+    _assert_phases(loop, driver)
+    # the off path passes no attributes and keeps nothing
+    assert all(e[3] == {} for e in loop)
+    assert tel.tracer.spans == [] and tel.events is None
+
+
+@pytest.mark.parametrize("driver", ["run", "run_batch"])
+def test_profiler_on_bit_identical(driver, tmp_path):
+    off = _drive(driver, tmp_path / "off")
+    on, events = _profiled(lambda: _drive(driver, tmp_path / "on"),
+                           tmp_path / "trace", driver)
+    assert _loop_events(events, driver)
+    if driver == "run":
+        off, on = [off], [on]
+    for o, n in zip(off, on):
+        _assert_identical(o, n)
 
 
 # -- sweep integration -------------------------------------------------------
