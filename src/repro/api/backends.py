@@ -353,13 +353,14 @@ class PallasBackend:
                             schedule=None, graph=None) -> Callable:
         from repro.core.algorithm1 import RoundOutput, SimState
         from repro.core.gossip import GossipState
-        from repro.api.shard_node import (ShardedSparseMixer, _pad_axis)
+        from repro.api.shard_node import ShardedSparseMixer
         from repro.api.clippers import PerNodeL2Clipper
+        from repro.core.privacy import slice_rows
 
         _check_supported(spec)
         rf = _round_kernels()
         m, n = part.m, spec.dim
-        block, m_pad_g = part.block, part.m_pad
+        block = part.block
         blk_pad, n_pad = rf._pad_rows(block), rf._pad_cols(n)
         for kernel in ("round_stats", "dual_step"):
             rf.col_block(kernel, blk_pad, n_pad, self.block_cols)
@@ -418,10 +419,7 @@ class PallasBackend:
 
             key, sub = jax.random.split(state.key)
             scale = mech.scale(ctx.alpha_t, n)
-            delta = mech.sample(sub, (m, n), scale)
-            delta = _pad_axis(delta, m_pad_g - m, 0)
-            delta = jax.lax.dynamic_slice_in_dim(delta, d * block, block,
-                                                 axis=0)
+            delta = mech.sample_rows(sub, (m, n), d * block, block, scale)
             tilde = theta + delta
 
             # mixing stays in XLA: the ppermute halo exchange + segment_sum
@@ -435,10 +433,8 @@ class PallasBackend:
 
             alive_blk = jnp.ones((block,), jnp.float32)
             if schedule is not None and schedule.has_crashes:
-                alive = _pad_axis(schedule.alive_f32(state.t),
-                                  m_pad_g - m, 0)
-                alive_blk = jax.lax.dynamic_slice_in_dim(
-                    alive, d * block, block, axis=0)
+                alive_blk = slice_rows(schedule.alive_f32(state.t),
+                                       d * block, block)
             theta_next = rf.dual_step(
                 _pad2(mixed, blk_pad, n_pad), x_p, theta_p,
                 _pad1(coeff, blk_pad), _pad1(alive_blk, blk_pad),

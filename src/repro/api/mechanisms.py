@@ -3,7 +3,9 @@
 A Mechanism owns (a) the per-round noise scale, calibrated to the Lemma-1
 sensitivity of the broadcast theta~, and (b) the sampler that perturbs the
 egress copies. Engines call ``scale`` once per round and ``sample`` once per
-state leaf; they never branch on what kind of mechanism is installed.
+state leaf; they never branch on what kind of mechanism is installed. A
+node-sharded round calls ``sample_rows`` instead: its shard's rows of the
+same sample, bit for bit.
 
 Calibrations (Laplace):
   'global'     — the paper's exact Lemma-1 L1 sensitivity 2*alpha_t*sqrt(n)*L
@@ -50,6 +52,18 @@ class Mechanism(Protocol):
         """Draw the egress perturbation (zeros when scale == 0)."""
         ...
 
+    def sample_rows(self, key: jax.Array, shape, row0, rows: int, scale,
+                    dtype=jnp.float32) -> jax.Array:
+        """Rows [row0, row0 + rows) of ``sample(key, shape, scale)``, bit
+        for bit; rows >= shape[0] are zeros."""
+        ...
+
+    def rows_drawn(self, key: jax.Array, shape, rows: int,
+                   dtype=jnp.float32) -> int:
+        """Rows of ``shape`` that ``sample_rows`` draws for a block of
+        ``rows``: ``rows`` when it draws the block alone."""
+        ...
+
 
 @dataclasses.dataclass(frozen=True)
 class LaplaceMechanism:
@@ -87,6 +101,14 @@ class LaplaceMechanism:
         from repro.core.privacy import sample_laplace
         return sample_laplace(key, shape, scale, dtype)
 
+    def sample_rows(self, key, shape, row0, rows, scale, dtype=jnp.float32):
+        from repro.core.privacy import sample_laplace_rows
+        return sample_laplace_rows(key, shape, row0, rows, scale, dtype)
+
+    def rows_drawn(self, key, shape, rows, dtype=jnp.float32):
+        from repro.core.privacy import rows_drawable
+        return rows if rows_drawable(key, shape, dtype) else shape[0]
+
 
 @dataclasses.dataclass(frozen=True)
 class GaussianMechanism:
@@ -113,6 +135,13 @@ class GaussianMechanism:
     def sample(self, key, shape, scale, dtype=jnp.float32):
         return jnp.asarray(scale, dtype) * jax.random.normal(key, shape, dtype)
 
+    def sample_rows(self, key, shape, row0, rows, scale, dtype=jnp.float32):
+        from repro.core.privacy import slice_rows
+        return slice_rows(self.sample(key, shape, scale, dtype), row0, rows)
+
+    def rows_drawn(self, key, shape, rows, dtype=jnp.float32):
+        return shape[0]
+
 
 @dataclasses.dataclass(frozen=True)
 class NoNoise:
@@ -129,6 +158,12 @@ class NoNoise:
 
     def sample(self, key, shape, scale, dtype=jnp.float32):
         return jnp.zeros(shape, dtype)
+
+    def sample_rows(self, key, shape, row0, rows, scale, dtype=jnp.float32):
+        return jnp.zeros((rows,) + tuple(shape[1:]), dtype)
+
+    def rows_drawn(self, key, shape, rows, dtype=jnp.float32):
+        return 0
 
 
 @MECHANISMS.register("laplace")

@@ -434,7 +434,7 @@ def run(spec: RunSpec | None, engine: str = "sim", *,
         chunk_fn, init_state = make_chunk_fn(spec, engine)
     else:
         from repro.api.shard_node import make_node_chunk_fn
-        chunk_fn, init_fn = make_node_chunk_fn(spec, engine, nmesh)
+        chunk_fn, init_fn = make_node_chunk_fn(spec, engine, nmesh, obs=tel)
         init_state = init_fn(jax.random.PRNGKey(spec.seed))
     chunk_jit = jax.jit(chunk_fn)
 
@@ -825,7 +825,7 @@ def run_batch(spec: RunSpec, seeds, engine: str = "sim", *,
         # the node-sharded chunk program vmaps the seed axis inside its own
         # ("seed","node") shard_map; the seed pad-and-mask stays out here
         chunk_jit = jax.jit(make_node_chunk_fn(base, engine, node_grid,
-                                               batched=True)[0])
+                                               batched=True, obs=tel)[0])
     else:
         mesh = _resolve_seed_mesh(devices, mesh)
         D = int(mesh.shape["seed"]) if mesh is not None else 1

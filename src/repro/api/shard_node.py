@@ -23,10 +23,11 @@ the dense n x n mixing matrix. This module shards it:
   unpadded, so checkpoints restore under any device count.
 
 Equivalence contract (tests/test_shard_node.py): the per-round Laplace
-noise is bit-identical to the dense engines — every shard draws the full
-(m, n) sample from the same per-round key and slices its own block — so a
-sharded run differs from dense `run()` only by float32 reduction order
-(segment_sum vs tensordot, psum'd metrics); the suite asserts the bound.
+noise is bit-identical to the dense engines — each shard draws only its
+own rows of the same (m, n) stream from the same per-round key
+(`Mechanism.sample_rows`), bit for bit — so a sharded run differs from
+dense `run()` only by float32 reduction order (segment_sum vs tensordot,
+psum'd metrics); the suite asserts the bound.
 
 >>> import jax
 >>> from repro.api import RunSpec
@@ -229,11 +230,10 @@ def reference_local_round_fn(spec: RunSpec, engine: str, part: NodePartition,
 
     Mirrors `Algorithm1.round` / `GossipDP.update` term for term; the only
     cross-shard traffic is the mixer's halo exchange and three metric psums.
-    The Laplace draw replays the dense engines' stream bit-for-bit: the full
-    (m, n) sample comes from the same per-round key on every shard, gets
-    zero-padded to m_pad rows (dynamic_slice clamps, so padding must happen
-    BEFORE the slice or the last shard would read overlapping rows) and each
-    shard keeps only its block.
+    The Laplace draw replays the dense engines' stream bit for bit: each
+    shard draws only its block of rows of the (m, n) sample from the same
+    per-round key (`Mechanism.sample_rows`); the padding rows of the last
+    shard come out zero.
 
     ``schedule`` (a `repro.faults.FaultSchedule`, with the global ``graph``
     it was wrapped around) swaps the mixer for `FaultyShardedSparseMixer`
@@ -244,9 +244,10 @@ def reference_local_round_fn(spec: RunSpec, engine: str, part: NodePartition,
     from repro.core.algorithm1 import (RoundOutput, SimState,
                                        hinge_loss_and_grad)
     from repro.core.gossip import GossipState
+    from repro.core.privacy import slice_rows
 
     m, n = part.m, spec.dim
-    block, m_pad = part.block, part.m_pad
+    block = part.block
     mech = spec.resolve_mechanism()
     rule = spec.resolve_local_rule()
     clipper = spec.resolve_clipper()
@@ -277,9 +278,7 @@ def reference_local_round_fn(spec: RunSpec, engine: str, part: NodePartition,
 
         key, sub = jax.random.split(state.key)
         scale = mech.scale(ctx.alpha_t, n)
-        delta = mech.sample(sub, (m, n), scale)
-        delta = _pad_axis(delta, m_pad - m, 0)
-        delta = jax.lax.dynamic_slice_in_dim(delta, d * block, block, axis=0)
+        delta = mech.sample_rows(sub, (m, n), d * block, block, scale)
         tilde = theta + delta
 
         if delay:
@@ -292,9 +291,8 @@ def reference_local_round_fn(spec: RunSpec, engine: str, part: NodePartition,
         if schedule is not None and schedule.has_crashes:
             # crashed rows of this block freeze (repro.faults), matching the
             # unsharded engines' hook; pad rows stay zero either way
-            alive = _pad_axis(schedule.alive_mask(state.t), m_pad - m, 0)
-            alive_blk = jax.lax.dynamic_slice_in_dim(alive, d * block, block,
-                                                     axis=0)
+            alive_blk = slice_rows(schedule.alive_mask(state.t), d * block,
+                                   block)
             theta_next = jnp.where(alive_blk[:, None], theta_next, theta)
 
         # global metrics: masked partial sums psum'd over the mesh axis —
@@ -342,7 +340,8 @@ def resolve_node_mesh(node_devices, mesh):
 
 
 def make_node_chunk_fn(spec: RunSpec, engine: str, mesh,
-                       batched: bool = False) -> tuple[Callable, Callable]:
+                       batched: bool = False,
+                       obs=None) -> tuple[Callable, Callable]:
     """Node-sharded (chunk_fn, init_fn) — drop-in for `make_chunk_program`.
 
     chunk_fn consumes and returns GLOBAL, unpadded state / data: the node
@@ -352,8 +351,14 @@ def make_node_chunk_fn(spec: RunSpec, engine: str, mesh,
     ``batched=True`` the per-chunk scan is vmapped over a leading seed axis
     and every spec gains a leading "seed" dim (the ("seed","node") grid
     `run_batch` uses).
+
+    Sets the gauge ``shard.noise_rows_drawn`` of ``obs`` (default: the
+    ambient `repro.obs.active()`, when enabled): the rows of the (m, n)
+    noise sample each shard draws per round — ``block`` when the
+    mechanism draws its rows alone, m when it draws the whole sample.
     """
     from jax.sharding import PartitionSpec as P
+    from repro import obs as obslib
     from repro.core.algorithm1 import RoundOutput
 
     if engine not in ("sim", "dist"):
@@ -410,6 +415,11 @@ def make_node_chunk_fn(spec: RunSpec, engine: str, mesh,
     init_fn = reference_chunk_program(spec, engine)[1]
 
     template = init_fn(jax.random.PRNGKey(0))
+    tel = obs if obs is not None else obslib.active()
+    if tel.enabled:
+        tel.metrics.gauge("shard.noise_rows_drawn").set(
+            spec.resolve_mechanism().rows_drawn(
+                template.key, (m, spec.dim), part.block))
     state_spec = _state_pspecs(template, lead)
     data_spec = P(*lead, None, "node")
     outs_spec = RoundOutput(loss=data_spec, w_bar_loss=P(*lead),

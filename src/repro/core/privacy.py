@@ -20,11 +20,16 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.extend.random import threefry2x32_p
 
 __all__ = [
     "sensitivity",
     "laplace_scale",
     "sample_laplace",
+    "sample_laplace_rows",
+    "rows_drawable",
+    "row_block_counters",
+    "slice_rows",
     "sample_laplace_tree",
     "clip_by_l2",
     "PrivacyConfig",
@@ -44,14 +49,107 @@ def laplace_scale(alpha_t: float | jax.Array, n: int, L: float, eps: float) -> j
     return sensitivity(alpha_t, n, L) / eps
 
 
+_U_MIN, _U_MAX = -0.5 + 1e-7, 0.5     # the uniform's range for Laplace
+
+
+def _laplace_from_uniform(u, scale, dtype):
+    noise = -jnp.sign(u) * jnp.log1p(-2.0 * jnp.abs(u))
+    return jnp.asarray(scale, dtype) * noise
+
+
 def sample_laplace(key: jax.Array, shape, scale, dtype=jnp.float32) -> jax.Array:
     """Laplace(0, scale) via inverse CDF: x = -scale * sign(u) * log1p(-2|u|).
 
     u ~ Uniform(-1/2, 1/2). Branch-free; exact for scale == 0 (returns zeros).
     """
-    u = jax.random.uniform(key, shape, dtype=dtype, minval=-0.5 + 1e-7, maxval=0.5)
-    noise = -jnp.sign(u) * jnp.log1p(-2.0 * jnp.abs(u))
-    return jnp.asarray(scale, dtype) * noise
+    u = jax.random.uniform(key, shape, dtype=dtype, minval=_U_MIN, maxval=_U_MAX)
+    return _laplace_from_uniform(u, scale, dtype)
+
+
+def _row_ids(row0, rows: int, ndim: int) -> jax.Array:
+    return jnp.asarray(row0) + jax.lax.broadcasted_iota(
+        jnp.int32, (rows,) + (1,) * (ndim - 1), 0)
+
+
+def slice_rows(full: jax.Array, row0, rows: int) -> jax.Array:
+    """Rows [row0, row0 + rows) of ``full``; rows past its end read 0.
+
+    Zero-pads BEFORE the slice: dynamic_slice clamps its start, so an
+    unpadded last block would read rows of the block before it.
+    """
+    m = full.shape[0]
+    full = jnp.pad(full, [(0, rows)] + [(0, 0)] * (full.ndim - 1))
+    block = jax.lax.dynamic_slice_in_dim(full, row0, rows, axis=0)
+    return jnp.where(_row_ids(row0, rows, full.ndim) < m, block,
+                     jnp.zeros((), full.dtype))
+
+
+def rows_drawable(key: jax.Array, shape, dtype=jnp.float32) -> bool:
+    """Whether `sample_laplace_rows` can draw a row block alone.
+
+    It can when element i of a draw from ``key`` is threefry2x32 of the
+    flat index i (``jax_threefry_partitionable``), the key is a raw
+    threefry key, the draw is float32 and a row's width fits 32 bits.
+    """
+    return bool(jax.config.jax_threefry_partitionable
+                and jax.config.jax_default_prng_impl == "threefry2x32"
+                and jnp.shape(key) == (2,)
+                and jnp.result_type(key) == jnp.uint32
+                and jnp.dtype(dtype) == jnp.float32
+                and math.prod(shape[1:]) < 2 ** 32)
+
+
+def _mul_wide(a: jax.Array, b: int) -> tuple[jax.Array, jax.Array]:
+    """(hi, lo) uint32 words of the 64-bit product a * b, a uint32, b < 2**32."""
+    a_lo, a_hi = a & 0xFFFF, a >> 16
+    b_lo, b_hi = jnp.uint32(b & 0xFFFF), jnp.uint32(b >> 16)
+    ll, lh, hl = a_lo * b_lo, a_lo * b_hi, a_hi * b_lo
+    mid = (ll >> 16) + (lh & 0xFFFF) + (hl & 0xFFFF)
+    hi = a_hi * b_hi + (lh >> 16) + (hl >> 16) + (mid >> 16)
+    return hi, a * jnp.uint32(b)
+
+
+def row_block_counters(row0, rows: int, n: int) -> tuple[jax.Array, jax.Array]:
+    """(hi, lo) uint32 words of the flat index (row0 + r) * n + j, the
+    threefry counter of element (row0 + r, j) of an (m, n) draw, as
+    (rows, n) arrays; the low word carries into the high one."""
+    g = jnp.asarray(row0).astype(jnp.uint32) + jax.lax.broadcasted_iota(
+        jnp.uint32, (rows, 1), 0)
+    base_hi, base_lo = _mul_wide(g, n)
+    lo = base_lo + jax.lax.broadcasted_iota(jnp.uint32, (rows, n), 1)
+    hi = base_hi + (lo < base_lo).astype(jnp.uint32)
+    return hi, lo
+
+
+@jax.jit
+def _uniform_f32(bits: jax.Array) -> jax.Array:
+    """jax.random.uniform's float32 transform of 32 random bits onto
+    [_U_MIN, _U_MAX): 23 mantissa bits under 1.0's exponent give [1, 2),
+    shifted to [0, 1) and scaled. Jitted as jax.random.uniform is, so that
+    an eager call compiles (and contracts) the same arithmetic."""
+    bits = jax.lax.shift_right_logical(bits, jnp.uint32(9))
+    one = jnp.asarray(jnp.array(1.0, jnp.float32).view(jnp.uint32))
+    floats = jax.lax.bitcast_convert_type(bits | one, jnp.float32) - 1.0
+    lo, hi = jnp.float32(_U_MIN), jnp.float32(_U_MAX)
+    return jax.lax.max(lo, floats * (hi - lo) + lo)
+
+
+def sample_laplace_rows(key: jax.Array, shape, row0, rows: int, scale,
+                        dtype=jnp.float32) -> jax.Array:
+    """Rows [row0, row0 + rows) of ``sample_laplace(key, shape, scale)``,
+    bit for bit, drawn without the rest; rows >= shape[0] come out 0.
+
+    ``row0`` may be traced (a shard's ``axis_index * block``). Where
+    `rows_drawable` says no, the whole sample is drawn and sliced.
+    """
+    if not rows_drawable(key, shape, dtype):
+        return slice_rows(sample_laplace(key, shape, scale, dtype), row0, rows)
+    m, n = shape[0], math.prod(shape[1:])
+    hi, lo = row_block_counters(row0, rows, n)
+    bits1, bits2 = threefry2x32_p.bind(key[0], key[1], hi, lo)
+    noise = _laplace_from_uniform(_uniform_f32(bits1 ^ bits2), scale, dtype)
+    noise = jnp.where(_row_ids(row0, rows, 2) < m, noise, jnp.zeros((), dtype))
+    return noise.reshape((rows,) + tuple(shape[1:]))
 
 
 def sample_laplace_tree(key: jax.Array, tree: Any, scale, dtype=None) -> Any:

@@ -5,8 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.api import MECHANISMS
 from repro.core.privacy import (
-    PrivacyAccountant, PrivacyConfig, clip_by_l2, laplace_scale, sample_laplace,
+    PrivacyAccountant, PrivacyConfig, clip_by_l2, laplace_scale,
+    row_block_counters, rows_drawable, sample_laplace, sample_laplace_rows,
     sample_laplace_tree, sensitivity,
 )
 
@@ -107,3 +109,110 @@ def test_accountant_rejects_invalid_input():
     with pytest.raises(ValueError):
         acc.step(-1)
     assert acc.rounds == 0  # the failed step must not half-apply
+
+
+# -- row blocks of one Laplace draw (the node-sharded round's noise) ---------
+
+def _full_rows(full, row0, rows):
+    """Rows [row0, row0 + rows) of ``full``, zeros past its end (numpy)."""
+    full = np.asarray(full)
+    out = np.zeros((rows,) + full.shape[1:], full.dtype)
+    part = full[row0:row0 + rows]
+    out[:len(part)] = part
+    return out
+
+
+@pytest.mark.parametrize("m,n,devices", [(10, 7, 4), (8, 10000, 4),
+                                         (13, 129, 3)])
+def test_sample_laplace_rows_is_the_full_draw_sliced(m, n, devices):
+    """Every shard's block, drawn alone under jit with a traced row0 and
+    under vmap over keys, equals the full draw's rows bit for bit; the
+    last shard's padding rows are exactly 0."""
+    block = -(-m // devices)
+    key = jax.random.PRNGKey(2 ** 31 + 77)
+    assert rows_drawable(key, (m, n))
+    full = sample_laplace(key, (m, n), 0.7)
+    draw = jax.jit(lambda k, r0: sample_laplace_rows(k, (m, n), r0, block,
+                                                     0.7))
+    keys = jax.random.split(key, 3)
+    fulls = [sample_laplace(k, (m, n), 0.7) for k in keys]
+    batched = jax.jit(jax.vmap(draw, in_axes=(0, None)))
+    for d in range(devices):
+        row0 = jnp.int32(d * block)
+        got = np.asarray(draw(key, row0))
+        np.testing.assert_array_equal(got, _full_rows(full, d * block, block))
+        pad = max(0, (d + 1) * block - m)
+        if pad:
+            assert not np.any(got[block - pad:])
+        for f, g in zip(fulls, np.asarray(batched(keys, row0))):
+            np.testing.assert_array_equal(g, _full_rows(f, d * block, block))
+
+
+@pytest.mark.parametrize("row0,rows,n", [
+    (0, 3, 7),
+    (42_946, 4, 100_003),           # 2**32 falls inside row 42,948
+    (2 ** 31 - 3, 2, 65_537),       # high word ~2**15
+    (429, 2, 10_000_019),           # the paper's widest n range
+])
+def test_row_block_counters_carry_into_the_high_word(row0, rows, n):
+    """The threefry counters are the 64-bit flat indices (row0 + r) * n + j,
+    checked against numpy uint64 without drawing the (m, n) sample."""
+    hi, lo = jax.jit(row_block_counters, static_argnums=(1, 2))(
+        jnp.int32(row0), rows, n)
+    flat = ((np.uint64(row0) + np.arange(rows, dtype=np.uint64))[:, None]
+            * np.uint64(n) + np.arange(n, dtype=np.uint64)[None, :])
+    np.testing.assert_array_equal(np.asarray(hi), (flat >> 32).astype(np.uint32))
+    np.testing.assert_array_equal(np.asarray(lo),
+                                  (flat & 0xFFFFFFFF).astype(np.uint32))
+
+
+@pytest.mark.parametrize("b", [1, 0xFFFF, 0x10000, 10 ** 8, 2 ** 32 - 1])
+def test_mul_wide_is_the_64_bit_product(b):
+    from repro.core.privacy import _mul_wide
+    a = np.concatenate([np.array([0, 1, 0xFFFF, 0x10000, 2 ** 32 - 1],
+                                 np.uint64),
+                        np.random.default_rng(b).integers(
+                            0, 2 ** 32, 64, dtype=np.uint64)])
+    hi, lo = _mul_wide(jnp.asarray(a.astype(np.uint32)), b)
+    want = [int(x) * b for x in a]
+    assert [(int(h) << 32) | int(l) for h, l in zip(hi, lo)] == want
+
+
+def test_sample_laplace_rows_falls_back_exactly():
+    """Without partitionable threefry, or with a typed key, the block is
+    the full draw sliced — the same numbers either way."""
+    m, n, rows = 10, 7, 3
+    key = jax.random.PRNGKey(5)
+    prev = jax.config.jax_threefry_partitionable
+    jax.config.update("jax_threefry_partitionable", False)
+    try:
+        assert not rows_drawable(key, (m, n))
+        full = sample_laplace(key, (m, n), 1.3)
+        for row0 in (0, 3, 9):
+            got = jax.jit(sample_laplace_rows, static_argnums=(1, 3))(
+                key, (m, n), jnp.int32(row0), rows, 1.3)
+            np.testing.assert_array_equal(np.asarray(got),
+                                          _full_rows(full, row0, rows))
+        assert MECHANISMS.build("laplace").rows_drawn(key, (m, n), rows) == m
+    finally:
+        jax.config.update("jax_threefry_partitionable", prev)
+    typed = jax.random.key(5)
+    assert not rows_drawable(typed, (m, n))
+    got = sample_laplace_rows(typed, (m, n), 9, rows, 1.3)
+    np.testing.assert_array_equal(
+        np.asarray(got), _full_rows(sample_laplace(typed, (m, n), 1.3), 9, rows))
+
+
+@pytest.mark.parametrize("name,drawn", [("laplace", 3), ("gaussian", 10),
+                                        ("none", 0)])
+def test_mechanism_sample_rows_matches_sample(name, drawn):
+    """Each mechanism's block form is its full sample's rows, bit for bit,
+    and ``rows_drawn`` says how many rows it draws for the block."""
+    mech = MECHANISMS.build(name)
+    key = jax.random.PRNGKey(11)
+    full = mech.sample(key, (10, 6), 0.4)
+    for row0 in (0, 3, 6, 9):
+        got = mech.sample_rows(key, (10, 6), row0, 3, 0.4)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      _full_rows(full, row0, 3))
+    assert mech.rows_drawn(key, (10, 6), 3) == drawn

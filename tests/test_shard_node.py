@@ -11,7 +11,9 @@ Multi-device equivalence runs in subprocesses with 8 fake CPU devices
     same device count is bit-identical (determinism / resume anchor);
   * checkpoints cross device counts: 4 -> 1 and 1 -> 4;
   * the ("seed","node") grid matches per-seed sequential runs;
-  * a node-sharded snapshot serves: verify_snapshot + batched predict.
+  * a node-sharded snapshot serves: verify_snapshot + batched predict;
+  * each shard draws only its block of the noise rows (the
+    ``shard.noise_rows_drawn`` gauge and the compiled program's shapes).
 
 In-process tests cover the 1-device fallback, the error surfaces, the
 mixer-to-sparse-graph lowering and the edge partitioner.
@@ -222,6 +224,38 @@ margins, labels = predict(snap.w, snap.w_bar, feats, nodes)
 ref = np.asarray(res.final_w).sum(axis=1)[np.asarray(nodes)]
 np.testing.assert_allclose(np.asarray(margins), ref, atol=1e-6)
 assert set(np.asarray(labels)) <= {-1.0, 1.0}
+print("OK")
+""")
+    assert "OK" in out
+
+
+@pytest.mark.slow
+def test_sharded_round_draws_only_its_rows():
+    """On a 4-device mesh each shard draws only its block of the noise: the
+    gauge ``shard.noise_rows_drawn`` reads ``block`` (m with a mechanism
+    that draws whole), and the compiled chunk program holds no (m, n)
+    random-bits array, only (block, n) ones."""
+    out = _run(r"""
+import jax
+from repro import obs
+from repro.api.shard_node import make_node_chunk_fn
+from repro.launch.mesh import node_mesh
+mesh = node_mesh(4)
+for mech, rows in (("laplace", 3), ("gaussian", 10), ("none", 0)):
+    tel = obs.Telemetry()
+    sp = spec(nodes=10, dim=8, mechanism=mech)
+    chunk_fn, init_fn = make_node_chunk_fn(sp, "sim", mesh, obs=tel)
+    assert tel.metrics.snapshot()["shard.noise_rows_drawn"] == rows, mech
+tel = obs.enable()
+try:
+    chunk_fn, init_fn = make_node_chunk_fn(spec(nodes=10, dim=8), "dist", mesh)
+    assert tel.metrics.snapshot()["shard.noise_rows_drawn"] == 3
+finally:
+    obs.disable()
+xs, ys = spec(nodes=10, dim=8).resolve_stream().chunk(0, 2)
+hlo = jax.jit(chunk_fn).lower(init_fn(jax.random.PRNGKey(0)), xs, ys
+                              ).compile().as_text()
+assert "u32[3,8]" in hlo and "u32[10,8]" not in hlo and "u32[12,8]" not in hlo
 print("OK")
 """)
     assert "OK" in out
