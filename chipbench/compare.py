@@ -2,9 +2,12 @@
 
 What is compared is what the timed path produced over the first
 ``compare_chunks`` chunks of a run (set-up drives them through the window's
-own chunk program, on pool chunks that all differ), and the privacy ledger
-of the whole run. Each number has its own limit, in the configuration file,
-set from readings of sound runs and of the control (PERF.md gives them):
+own chunk program, on chunks that all differ), and the privacy ledger of
+the whole run. Each number has its own limit, in the configuration file,
+set from readings of sound runs and of the control (PERF.md gives them).
+`judge` holds a run to every name that the configuration's ``limits`` gives;
+`readings` gives the linear learner's numbers (`chipbench.drivers`' default
+driver):
 
   loss_gap        widest |loss| gap over the rounds and nodes
   w_bar_loss_gap  widest |w_bar_loss| gap over the rounds
@@ -52,11 +55,12 @@ def readings(prog: dict, ref: dict, *, entries: int) -> dict:
 
 
 def judge(values: dict, limits: dict) -> tuple[bool, dict]:
-    """(correct, {name: {"value": v, "limit": l}}): every number within its
-    limit; a number that is not finite fails."""
+    """(correct, {name: {"value": v, "limit": l}}) for every name in
+    ``limits``: each number within its limit; a number that is not finite,
+    or that ``values`` lacks (read as inf), fails."""
     checks, ok = {}, True
-    for name in NUMBERS:
-        v, lim = values[name], float(limits[name])
+    for name, lim in limits.items():
+        v, lim = values.get(name, math.inf), float(lim)
         passed = math.isfinite(v) and v <= lim
         ok = ok and passed
         checks[name] = {"value": v, "limit": lim}

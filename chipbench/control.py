@@ -1,4 +1,5 @@
-"""The readings that the limits of `correct` are set from, on the chip.
+"""The readings that the limits of `correct` are set from, on the chip, for
+a cell of the linear learner (`chipbench/drivers/gossip_linear.py`).
 
     python3 chipbench/control.py --workload <cell> --seeds 100-111 \
         --control-seeds 200-202 [--out readings.jsonl]
@@ -32,6 +33,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from chipbench import compare, harness  # noqa: E402
+from chipbench.drivers import gossip_linear as linear  # noqa: E402
 
 
 def seeds(text: str) -> list:
@@ -42,29 +44,23 @@ def seeds(text: str) -> list:
     return out
 
 
-def program_outputs(cell: dict, seed: int):
-    """(program outputs over the compared chunks, traffic, ref sharding)."""
-    from repro.api import run
+def program_readings(cell: dict, seed: int) -> dict:
+    """The compared numbers of the program over the compared chunks: set-up
+    as a benchmark run makes it, with no window."""
+    drv = linear.Driver(cell, seed)
+    drv.prepare()
+    count = [0]
 
-    cfg = cell["config"]
-    K = int(cfg["compare_chunks"])
-    spec, exec_cfg, traffic, ref_sharding = harness.build(cell, seed)
-    traffic.prepare()
-    kept = {}
-
-    def stop(round_end, state, accountant):
-        kept["n"] = kept.get("n", 0) + 1
-        if kept["n"] == K:
-            kept["state"] = (state.theta, state.t)
+    def stop(*args):
+        count[0] += 1
+        if count[0] == drv.open_at:
+            drv.keep(*args)
             return True
         return False
-    res = run(spec, engine="sim", exec=exec_cfg, on_chunk=stop)
-    theta, t = kept.pop("state")
-    w = spec.resolve_local_rule().primal(theta, spec.omd_config().step_context(t))
-    out = {"loss": res.loss, "correct": res.correct,
-           "w_bar_loss": res.w_bar_loss, "sparsity": res.sparsity,
-           "eps": res.eps_ledger, "w": w}
-    return out, traffic, ref_sharding
+    drv.run(stop)
+    values = drv.readings(drv.open_at)
+    drv.release()
+    return values
 
 
 def faulty(base, fault: str, chips: int):
@@ -99,7 +95,7 @@ def reference_outputs(cell: dict, seed: int, traffic, sharding,
     import jax
 
     cfg = cell["config"]
-    refmod = harness.reference_module(cfg["reference"]["module"])
+    refmod = linear.reference_module(cfg["reference"]["module"])
     cls = refmod.Reference if fault is None else \
         faulty(refmod.Reference, fault, cell["chips"])
     ref = cls(cfg, precision=precision, sharding=sharding)
@@ -108,7 +104,7 @@ def reference_outputs(cell: dict, seed: int, traffic, sharding,
         out = ref.run(seed, [traffic.chunk_data(k) for k in range(K)],
                       follow=follow)
     out["eps"] = refmod.eps_ledger(cfg["spec"]["eps"],
-                                   K * harness.sizes(cfg)[2])
+                                   K * linear.sizes(cfg)[2], traffic.disjoint)
     if fault == "altered":
         out["loss"][-1, 0] += 1e-3
     return out
@@ -129,7 +125,7 @@ def main(argv=None) -> int:
     device = harness.check_devices(cell["chips"])
     harness.enable_cache()
     cfg = cell["config"]
-    nodes, dim, _ = harness.sizes(cfg)
+    nodes, dim, _ = linear.sizes(cfg)
     entries = nodes * dim
     sink = open(args.out, "a") if args.out else None
     rows = []
@@ -146,14 +142,8 @@ def main(argv=None) -> int:
 
     for seed in seeds(args.seeds):
         t0 = time.perf_counter()
-        prog, traffic, sharding = program_outputs(cell, seed)
-        gc.collect()
-        ref = reference_outputs(cell, seed, traffic, sharding,
-                                follow=prog["loss"])
-        emit("program", seed, compare.readings(prog, ref, entries=entries),
+        emit("program", seed, program_readings(cell, seed),
              time.perf_counter() - t0)
-        del prog, ref
-        traffic.release()
         gc.collect()
 
     variants = [("control", cfg["control"], None)]
@@ -163,7 +153,7 @@ def main(argv=None) -> int:
         if cell["chips"] > 1:
             variants.append(("no_exchange", "highest", "no_exchange"))
     for seed in seeds(args.control_seeds):
-        _, _, traffic, sharding = harness.build(cell, seed)
+        _, _, traffic, sharding = linear.build(cell, seed)
         traffic.prepare()
         for kind, precision, fault in variants:
             t0 = time.perf_counter()

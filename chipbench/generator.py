@@ -1,5 +1,5 @@
 """The one traffic generator: the paper's social_sparse stream, replayed from
-a pool made at set-up.
+a pool made at set-up or generated chunk by chunk as it arrives.
 
 The data is the benchmark's own copy of the §V workload (a fixed sparse w*,
 gaussian features scaled by 1/sqrt(n), labels sign(<w*, x>) with optional
@@ -10,22 +10,34 @@ changes. The seed is a traced argument: every seed runs the same compiled
 programs.
 
 A traffic mix is a JSON file of parameters (``chipbench/traffic/<name>.json``)
-that `Traffic` reads: ``mode`` "replay", the pool's ``pool_chunks``, the
-ground truth's ``sparsity_true`` and the ``label_noise``. A pool of
-``pool_chunks`` chunks is made on the device at set-up, in one jitted call
-from the seed; chunk k of a run is pool chunk k mod pool_chunks, handed over
-as it is (no copy, no program), so the window runs nothing but the chunk
-program. The pool repeats rows, so the stream declares itself not disjoint
-and the privacy accountant composes sequentially.
+that `Traffic` reads: the ``mode``, the ground truth's ``sparsity_true`` and
+the ``label_noise``, and for a replay the pool's ``pool_chunks``.
+
+  replay  a pool of ``pool_chunks`` chunks is made on the device at set-up,
+          in one jitted call from the seed; chunk k of a run is pool chunk
+          k mod pool_chunks, handed over as it is (no copy, no program), so
+          the window runs nothing but the chunk program. The pool repeats
+          rows, so the stream declares itself not disjoint and the privacy
+          accountant composes sequentially.
+  stream  w* is made at set-up and the generator's program compiled there;
+          chunk k is made on the device when the runner asks for it, by one
+          call of that program (``STREAM_PROGRAM`` in a trace) for rounds
+          [k c, (k + 1) c): the same rows as pool chunk k of a replay, bit
+          for bit. Every round's rows are fresh, so the stream declares
+          itself disjoint and the accountant composes in parallel (flat
+          eps, the paper's Theorem 1).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # Labels are the sign of a float32 contraction, pinned to HIGHEST so that
 # they are a function of the seed alone and not of the ambient precision.
 LABEL_PRECISION = jax.lax.Precision.HIGHEST
+MODES = ("replay", "stream")
+STREAM_PROGRAM = "jit_stream_chunk"     # the stream's generator, in a trace
 
 
 def seed_array(seed: int) -> jax.Array:
@@ -68,13 +80,13 @@ class Traffic:
     ``annotate`` wraps each ``chunk`` call in a profiler annotation.
     """
 
-    disjoint = False        # the pool repeats rows
-
     def __init__(self, mix: dict, *, n: int, nodes: int, chunk_rounds: int,
                  horizon: int, seed: int, shardings=None, annotate=None):
-        if mix.get("mode") != "replay":
-            raise ValueError(f"traffic mode {mix.get('mode')!r}: the "
-                             "generator replays a pool ('replay') only")
+        if mix.get("mode") not in MODES:
+            raise ValueError(f"traffic mode {mix.get('mode')!r}: one of "
+                             f"{MODES}")
+        self.mode = mix["mode"]
+        self.disjoint = self.mode == "stream"   # a pool repeats its rows
         self.n, self.nodes, self.rounds = n, nodes, horizon
         self.chunk_rounds = chunk_rounds
         self.sparsity_true = float(mix.get("sparsity_true", 0.05))
@@ -83,10 +95,14 @@ class Traffic:
         self._seed = seed_array(seed)
         self._annotate = annotate
         self._shardings = shardings
-        self._pool = None
+        self._pool = self._w = self._generate = None
 
     def prepare(self) -> None:
-        """Set-up: make the pool on the device, in one jitted call."""
+        """Set-up: make the pool on the device, in one jitted call; or make
+        w* and compile the stream's generator."""
+        if self.mode == "stream":
+            self._prepare_stream()
+            return
         chunks, c = self.pool_chunks, self.chunk_rounds
         out = None if self._shardings is None else (self._shardings,) * chunks
 
@@ -97,9 +113,20 @@ class Traffic:
         self._pool = jax.block_until_ready(
             jax.jit(make, out_shardings=out)(self._seed))
 
+    def _prepare_stream(self) -> None:
+        nodes, c, noise = self.nodes, self.chunk_rounds, self.label_noise
+
+        def stream_chunk(w, seed, t0):
+            return rounds(w, nodes, seed, t0, c, noise)
+        self._w = jax.block_until_ready(jax.jit(
+            w_true, static_argnums=(0, 1))(self.n, self.sparsity_true,
+                                           self._seed))
+        self._generate = jax.jit(stream_chunk, out_shardings=self._shardings)
+        jax.block_until_ready(self.chunk_data(0))
+
     def release(self) -> None:
-        """Drop the pool, so that its device memory can be freed."""
-        self._pool = None
+        """Drop the pool or w*, so that their device memory can be freed."""
+        self._pool = self._w = None
 
     def chunk(self, t0: int, t1: int) -> tuple[jax.Array, jax.Array]:
         """Rounds [t0, t1): one aligned chunk (the `repro.api` Stream call)."""
@@ -114,4 +141,7 @@ class Traffic:
 
     def chunk_data(self, k: int) -> tuple[jax.Array, jax.Array]:
         """The data of the run's k-th chunk (0-based)."""
+        if self.mode == "stream":
+            return self._generate(self._w, self._seed,
+                                  np.int32(k * self.chunk_rounds))
         return self._pool[k % self.pool_chunks]

@@ -3,23 +3,26 @@
 A cell (an entry of ``workloads`` in BENCHMARK.json) names a configuration
 (``chipbench/configs/<name>.json``) and a traffic mix
 (``chipbench/traffic/<name>.json``); its per-layer metrics are read by
-``chipbench/metrics/<name>.py``. Everything is found by name, so a new cell,
-mix or metric is new files and new entries, and no edit here.
+``chipbench/metrics/<name>.py``, and the configuration's ``driver`` (by
+default ``gossip_linear``) is ``chipbench/drivers/<name>.py``. Everything is
+found by name, so a new cell, mix, metric or driver is new files and new
+entries, and no edit here.
 
-The run drives the system under test, `repro.api.run`, once:
+The driver runs the system under test and says what one operation is
+(`chipbench.drivers`); the harness keeps what every cell shares:
 
-  set-up   the traffic is made on the device from the seed; `run` compiles
-           its chunk program and then runs ``compare_chunks`` chunks through
-           it (the chunks the reference follows);
-  window   opens at that chunk's ``on_chunk`` and closes at the first
-           ``on_chunk`` at least ``--seconds`` later, which stops the run;
-           one operation is one chunk;
-  check    once the window has closed, the peak memory read and the
-           program's state freed, the plain reference follows the compared
-           chunks from the seed and `chipbench.compare` judges the outputs.
+  set-up   the driver's ``prepare`` (the traffic), then its ``run`` up to the
+           ``open_at``-th operation (the operations the reference follows);
+  window   opens at that operation's hook and closes at the first hook at
+           least ``--seconds`` later, which stops the run; the end-to-end
+           numbers come from the window's marks on the harness's own clock;
+  check    once the window has closed and the peak memory is read, the
+           driver's ``readings`` (the program's state freed, then the plain
+           reference) and `chipbench.compare.judge` against the limits.
 
 With ``--trace 1`` the profiler records the first second or so of the
-window (whole chunks) and the per-layer metrics are read from that trace.
+window (whole operations) and the per-layer metrics are read from that
+trace, with the driver's timed program and ``info``.
 """
 from __future__ import annotations
 
@@ -46,9 +49,28 @@ class NoChip(RuntimeError):
 
 # -- finding things by name --------------------------------------------------
 
+DEFAULT_DRIVER = "gossip_linear"
+
+
 def _json(path: Path) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def driver_file(name: str, root: Path = ROOT) -> Path:
+    """``chipbench/drivers/<name>.py``; a name with no such file is refused."""
+    path = root / "chipbench" / "drivers" / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"no driver {name!r}: {path.relative_to(root)} "
+                         "does not exist")
+    return path
+
+
+def _module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_cell(name: str, root: Path = ROOT) -> dict:
@@ -63,28 +85,28 @@ def load_cell(name: str, root: Path = ROOT) -> dict:
     def applies(metric):
         return name in metric.get("workloads", [name])
 
+    config = _json(root / "chipbench" / "configs" / f"{wl['config']}.json")
     return {
         "name": name,
         "chips": int(wl["chips"]),
-        "config": _json(root / "chipbench" / "configs" / f"{wl['config']}.json"),
+        "config": config,
         "traffic": _json(root / "chipbench" / "traffic" / f"{wl['traffic']}.json"),
+        "driver": str(driver_file(config.get("driver", DEFAULT_DRIVER), root)),
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
         "per_layer": [m for m in bench["per_layer"] if applies(m)],
     }
 
 
+def driver(cell: dict):
+    """The ``Driver`` class of the cell's driver file."""
+    path = Path(cell["driver"])
+    return _module(path, f"chipbench.drivers.{path.stem}").Driver
+
+
 def reader(metric: str, root: Path = ROOT):
     """The ``read(reduction, cell)`` function of one per-layer metric."""
     path = root / "chipbench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench.metrics.{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
-
-
-def reference_module(name: str):
-    return importlib.import_module(f"chipbench.references.{name}")
+    return _module(path, f"chipbench.metrics.{metric.replace('.', '_')}").read
 
 
 # -- the machine -------------------------------------------------------------
@@ -140,53 +162,17 @@ def memory_peak(chips: int) -> int:
     return int(max(peaks))
 
 
-# -- the system under test ---------------------------------------------------
-
-def sizes(cfg: dict) -> tuple[int, int, int]:
-    """(nodes, dim, chunk_rounds) of a configuration."""
-    return (int(cfg["spec"]["nodes"]), int(cfg["spec"]["dim"]),
-            int(cfg["exec"]["chunk_rounds"]))
-
-
-def build(cell: dict, seed: int):
-    """(spec, exec config, traffic, reference sharding) of one run.
-
-    The configuration's ``spec`` and ``exec`` go into `RunSpec` and
-    `ExecConfig` as they are; the harness adds only the stream (the cell's
-    traffic), the seed and, on more than one chip, the node mesh."""
-    import jax
-    from repro.api import ExecConfig, RunSpec
-
-    from chipbench.generator import Traffic
-
-    cfg, chips = cell["config"], cell["chips"]
-    nodes, dim, chunk_rounds = sizes(cfg)
-    mesh = shardings = ref_sharding = None
-    if chips > 1:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import node_mesh
-        mesh = node_mesh(chips)
-        data = NamedSharding(mesh, P(None, "node"))
-        shardings = (data, data)
-        ref_sharding = NamedSharding(mesh, P("node", None))
-    traffic = Traffic(cell["traffic"], n=dim, nodes=nodes,
-                      chunk_rounds=chunk_rounds,
-                      horizon=int(cfg["spec"]["horizon"]), seed=seed,
-                      shardings=shardings,
-                      annotate=jax.profiler.TraceAnnotation)
-    spec = RunSpec(**cfg["spec"], seed=int(seed) % 2**32, stream=traffic)
-    exec_cfg = ExecConfig(**cfg["exec"], node_mesh=mesh)
-    return spec, exec_cfg, traffic, ref_sharding
-
+# -- the window --------------------------------------------------------------
 
 class Window:
-    """The ``on_chunk`` hook: keeps the compared state, opens and closes the
-    window, and starts and stops the trace."""
+    """The hook the driver calls after every operation: has the driver keep
+    the compared state, opens and closes the window, and starts and stops
+    the trace."""
 
-    def __init__(self, seconds: float, open_at: int, trace: bool):
+    def __init__(self, seconds: float, open_at: int, trace: bool, keep):
         self.seconds, self.open_at, self.trace = seconds, open_at, trace
+        self.keep = keep            # the driver's, called at the open
         self.k = 0
-        self.compared = None        # (theta, t) after the last compared chunk
         self.t_open = self.t_close = None
         self.marks: list = []       # perf_counter at each chunk in the window
         self.usage: list = []       # usage() at the window's open and marks
@@ -208,16 +194,14 @@ class Window:
                 and self.t_close is None:
             self.compiles += 1
 
-    def __call__(self, round_end, state, accountant) -> bool:
+    def __call__(self, *args) -> bool:
         import jax
 
         now = time.perf_counter()
         self.k += 1
         with jax.profiler.TraceAnnotation("chipbench.on_chunk"):
             if self.k == self.open_at:
-                theta = state.theta if not isinstance(state.theta, dict) \
-                    else state.theta["w"]
-                self.compared = (theta, state.t)
+                self.keep(*args)
                 if self.trace:
                     self._start_trace()
                 self.steal = host_steal_s()
@@ -262,22 +246,18 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, t0: float,
              device: dict) -> dict:
     """One run of ``cell``; returns the result line as a dict."""
     import jax
-    from repro.api import run
 
     from chipbench import compare
 
-    cfg = cell["config"]
-    nodes, dim, chunk_rounds = sizes(cfg)
-    K = int(cfg["compare_chunks"])
     phases = {"start": time.perf_counter() - t0}
-    spec, exec_cfg, traffic, ref_sharding = build(cell, seed)
-    traffic.prepare()
+    drv = driver(cell)(cell, seed)
+    drv.prepare()
     phases["traffic"] = time.perf_counter() - t0
-    window = Window(seconds, open_at=K, trace=trace)
+    window = Window(seconds, open_at=drv.open_at, trace=trace, keep=drv.keep)
     jax.monitoring.register_event_duration_secs_listener(window.on_compile)
     gc.callbacks.append(window.on_gc)
     try:
-        res = run(spec, engine="sim", exec=exec_cfg, on_chunk=window)
+        drv.run(window)
     finally:
         gc.callbacks.remove(window.on_gc)
     if window.t_close is None:
@@ -288,31 +268,13 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, t0: float,
     span = window.t_close - window.t_open
     marks = [window.t_open] + window.marks
     chunk_s = [b - a for a, b in zip(marks, marks[1:])]
-    rounds = len(window.marks) * chunk_rounds
-    e2e = {"samples_per_s": nodes * rounds / span,
+    e2e = {"samples_per_s": drv.samples_per_op * len(window.marks) / span,
            "chunk_p95_ms": 1e3 * _percentile(chunk_s, 95),
            "setup_s": window.t_open - t0}
 
-    # free the program's state, then the reference follows the compared chunks
-    theta, t = window.compared
-    rule, omd = spec.resolve_local_rule(), spec.omd_config()
-    w_prog = rule.primal(theta, omd.step_context(t))
-    R = K * chunk_rounds
-    prog = {"loss": res.loss[:R], "correct": res.correct[:R],
-            "w_bar_loss": res.w_bar_loss[:R], "sparsity": res.sparsity[:R],
-            "eps": res.eps_ledger, "w": w_prog}
-    res.final_state = None
-    del theta, window.compared
-    gc.collect()
-    refmod = reference_module(cfg["reference"]["module"])
-    ref = refmod.Reference(cfg, precision="highest", sharding=ref_sharding)
-    with jax.default_matmul_precision("highest"):
-        out = ref.run(seed, [traffic.chunk_data(k) for k in range(K)],
-                      follow=prog["loss"])
-    out["eps"] = refmod.eps_ledger(cfg["spec"]["eps"], window.k * chunk_rounds)
-    values = compare.readings(prog, out, entries=nodes * dim)
-    correct, checks = compare.judge(values, cfg["limits"])
-    traffic.release()
+    values = drv.readings(window.k)
+    correct, checks = compare.judge(values, cell["config"]["limits"])
+    drv.release()
 
     result = {"correct": correct, "attempted": len(window.marks),
               "failed": 0}
@@ -322,7 +284,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, t0: float,
                              for m in cell["end_to_end"]}
         result["device"] = out_device
     else:
-        metrics, extra, bd = read_trace(cell, device["kind"])
+        metrics, extra, bd = read_trace(cell, device["kind"], drv)
         result["metrics"] = metrics
         result["device"] = dict(out_device, **extra)
         result["breakdown"] = bd
@@ -343,16 +305,14 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, t0: float,
     return result
 
 
-def read_trace(cell: dict, kind: str) -> tuple[dict, dict, dict]:
+def read_trace(cell: dict, kind: str, drv) -> tuple[dict, dict, dict]:
     """(per-layer metrics, busy_s/window_s, breakdown) from the window's
-    trace."""
+    trace, with the driver's timed program and reader ``info``."""
     from chipbench import peaks, trace
 
-    r = trace.reduce_trace(trace.find_xplane(str(TRACE_DIR)))
-    nodes, dim, chunk_rounds = sizes(cell["config"])
-    info = {"m": nodes, "n": dim, "chips": cell["chips"],
-            "chunk_rounds": chunk_rounds, "peaks": peaks.peaks(kind),
-            "rounds": trace.rounds_traced(r, chunk_rounds)}
+    r = trace.reduce_trace(trace.find_xplane(str(TRACE_DIR)), drv.program)
+    info = dict(drv.info, chips=cell["chips"], peaks=peaks.peaks(kind),
+                rounds=trace.rounds_traced(r, drv.info["chunk_rounds"]))
     metrics = {}
     for m in cell["per_layer"]:
         value = reader(m["name"])(r, info)

@@ -13,25 +13,32 @@ publication in a deployment. Layer: the runner's chunk loop. Moves
 samples_per_s. Returns nothing where the trace has none of these spans (a
 runner without them) or fewer than two launches.
 
-A launch is the end of the host's ``PJRT_LoadedExecutable_Execute`` inside
-a ``run.dispatch`` span: the moment the host has handed the chunk program
-to the chip. `runner_exposed_us` and `runner_sync_idle_us` lay the host's
-spans against each chip's idle gaps, and the profiler puts a TPU's plane on
-the host's clock only to within about a millisecond (the offset differs
-from trace to trace). So `on_chip` first moves the host spans onto each
-chip's clock by the median, over the chip's chunk programs in the window,
-of (program start - the nearest launch), which holds while the offset is
-under half a chunk period: after it a program starts, in the median, when
-its launch returns. The median launch latency is so taken as 0. Where it
-is not (programs started 87-131 us after their launch returned in
+A launch is the end of the host's ``PJRT_LoadedExecutable_Execute``: the
+moment the host has handed a program to the chip; the chunk program's is
+the last inside a ``run.dispatch`` span. `runner_exposed_us` and
+`runner_sync_idle_us` lay the host's spans against each chip's idle gaps,
+and the profiler puts a TPU's plane on the host's clock only to within
+about a millisecond (the offset differs from trace to trace). So `on_chip`
+first moves the host spans onto each chip's clock by the median, over the
+loop iterations in the window, of (the start of the iteration's first
+program on the chip - the nearest first launch of an iteration on the
+host), which holds while the offset is under half a chunk period: after it
+the program that the chip waited for starts, in the median, when its
+launch returns. An iteration's first program is the earliest that starts
+after the previous chunk program ended: the chunk program itself where the
+loop launches nothing else, a stream's generator where that runs first (the
+chunk program then queues behind it, and starts later than its launch).
+The median launch latency is so taken as 0. Where it is not (programs
+started 87-131 us after their launch returned in
 `tests/data/small_stream.xplane.pb`), the host's phases sit that much later
 in the gap than they ran, and their share of it holds as long as they stay
-inside it: only the rest of ``run.dispatch`` after the launch moves from
+inside it: only the rest of the launching span after the launch moves from
 the gap to the program. All this holds in a loop that blocks on each chunk,
-as `repro.api.run`'s does, where every program waits for its launch. In a
-loop that dispatches ahead, programs queue behind the one before and start
-later than their launch; the offset then has to come from the programs the
-chip waited for."""
+as `repro.api.run`'s does, where each iteration's first program waits for
+its launch. In a loop that dispatches ahead, an iteration's programs queue
+behind the one before; the offset then has to come from programs that
+start after the chip was idle."""
+import math
 import statistics
 
 from chipbench import trace
@@ -59,13 +66,36 @@ def launches(r: trace.Reduction) -> list:
     return out
 
 
+def iteration_launches(r: trace.Reduction) -> list:
+    """The first program launch of each loop iteration: the earliest launch
+    after the previous iteration's chunk launch, up to its own."""
+    ends = sorted(b for name, a, b in r.host if name == LAUNCH)
+    out, prev = [], -math.inf
+    for c in launches(r):
+        out.append(min(e for e in ends if prev < e <= c))
+        prev = c
+    return out
+
+
+def iteration_starts(dev: trace.Device) -> list:
+    """The start of each loop iteration's first program on one chip: the
+    earliest program that starts after the previous chunk program ended,
+    up to its own chunk program's start."""
+    starts = sorted(a for _, a, _ in dev.modules)
+    out, prev = [], -math.inf
+    for a, b in trace.chunk_spans(dev):
+        out.append(min(s for s in starts if prev <= s <= a))
+        prev = b
+    return out
+
+
 def on_chip(r: trace.Reduction) -> list:
     """[(chip, the host's spans moved onto its clock)] for each chip that ran
     a chunk program in the window; [] where the trace has no launch."""
-    launched = launches(r)
+    launched = iteration_launches(r)
     out = []
     for dev in r.devices:
-        starts = [a for a, _ in trace.chunk_spans(dev)]
+        starts = iteration_starts(dev)
         if not launched or not starts:
             continue
         shift = statistics.median(

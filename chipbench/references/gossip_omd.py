@@ -223,8 +223,11 @@ def _primal_jit(theta, t, alpha0, lam):
     return jnp.sign(theta) * jnp.maximum(jnp.abs(theta) - alpha * lam, 0.0)
 
 
-def eps_ledger(eps: float, rounds: int) -> np.ndarray:
-    """The cumulative guarantee after each of ``rounds`` rounds under
-    sequential composition, eps * t: a replayed pool repeats rows, so its
-    rounds are not disjoint."""
+def eps_ledger(eps: float, rounds: int, disjoint: bool = False) -> np.ndarray:
+    """The cumulative guarantee after each of ``rounds`` rounds. A stream
+    whose rounds touch disjoint rows composes in parallel (the paper's
+    Theorem 1): eps after every round. One that repeats rows, as a replayed
+    pool does, composes sequentially: eps * t."""
+    if disjoint:
+        return np.full(rounds, eps, np.float64)
     return eps * np.arange(1, rounds + 1, dtype=np.float64)

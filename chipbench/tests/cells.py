@@ -21,6 +21,11 @@ def sec5_tiny() -> dict:
                      {"chunk_rounds": 8})
 
 
+def sec5_stream_tiny() -> dict:
+    return tiny_cell("sec5.stream", {"nodes": 16, "dim": 384},
+                     {"chunk_rounds": 8})
+
+
 def ring64k_tiny() -> dict:
     return tiny_cell("ring64k.sharded4", {"nodes": 64, "dim": 256},
                      {"chunk_rounds": 2})

@@ -9,11 +9,14 @@ over 1,536 rounds: 0.041, 27 and 0.091). ring64k's bfloat16 control fails
 at any size."""
 import pytest
 
-from chipbench import compare, control, harness
+from chipbench import compare, control
+from chipbench.drivers import gossip_linear as linear
 from chipbench.tests import cells
 
 CASES = {
     "sec5.replay": lambda: cells.tiny_cell("sec5.replay", {},
+                                           {"chunk_rounds": 128}),
+    "sec5.stream": lambda: cells.tiny_cell("sec5.stream", {},
                                            {"chunk_rounds": 128}),
     "ring64k.sharded4": cells.ring64k_tiny,
 }
@@ -23,13 +26,13 @@ CASES = {
 def test_control_is_not_correct(name):
     cell = CASES[name]()
     cfg = cell["config"]
-    _, _, traffic, sharding = harness.build(cell, cells.SEED)
+    _, _, traffic, sharding = linear.build(cell, cells.SEED)
     traffic.prepare()
     low = control.reference_outputs(cell, cells.SEED, traffic, sharding,
                                     precision=cfg["control"])
     ref = control.reference_outputs(cell, cells.SEED, traffic, sharding,
                                     follow=low["loss"])
-    nodes, dim, _ = harness.sizes(cfg)
+    nodes, dim, _ = linear.sizes(cfg)
     entries = nodes * dim
     alone = control.reference_outputs(cell, cells.SEED, traffic, sharding)
     same, _ = compare.judge(compare.readings(alone, alone, entries=entries),
@@ -62,7 +65,7 @@ def test_reference_follows_the_compared_side():
     import numpy as np
 
     cell = cells.ring64k_tiny()
-    _, _, traffic, sharding = harness.build(cell, cells.SEED)
+    _, _, traffic, sharding = linear.build(cell, cells.SEED)
     traffic.prepare()
     alone = control.reference_outputs(cell, cells.SEED, traffic, sharding)
     same = control.reference_outputs(cell, cells.SEED, traffic, sharding,

@@ -5,7 +5,8 @@ import pytest
 
 from chipbench.tests import cells
 
-CELLS = {"sec5.replay": cells.sec5_tiny, "ring64k.sharded4": cells.ring64k_tiny}
+CELLS = {"sec5.replay": cells.sec5_tiny, "sec5.stream": cells.sec5_stream_tiny,
+         "ring64k.sharded4": cells.ring64k_tiny}
 
 
 def _wrap_chunk_program(monkeypatch, sharded: bool, wrap):

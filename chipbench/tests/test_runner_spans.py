@@ -129,6 +129,39 @@ def test_mean_over_chips_and_gaps():
     assert v["runner_host_us"] == 7.0
 
 
+@pytest.mark.parametrize("shift", [0.0, 4.0])
+def test_generator_before_the_chunk_aligns_on_the_first_program(shift):
+    # a stream's loop: each iteration launches the generator (run.stream)
+    # and then the chunk program, which queues behind it and starts 9 us
+    # after its launch returns. Chunks 0-10, 30-40, 60-70 us and the
+    # generator 20-30, 50-60 us on the chip; each 10 us gap is 2 us of
+    # run.wait, 5 of fetch and 3 of run.stream up to the generator's
+    # launch. The host is aligned on the generator, the program the chip
+    # waited for, and not on the chunk program.
+    gen = [(-10, 0), (20, 30), (50, 60)]
+    chunks = [(0, 10), (30, 40), (60, 70)]
+    dev = trace.Device(
+        "/device:TPU:0",
+        sorted([("jit_stream_chunk", a, b) for a, b in ns(gen, shift)]
+               + [(PROG, a, b) for a, b in ns(chunks, shift)],
+               key=lambda m: m[1]),
+        [trace.Op("fusion.1", "fusion", "fusion", "xla", a, b, "")
+         for a, b in ns(sorted(gen + chunks), shift)])
+    host = [("run.stream", -13, -10), (LAUNCH, -10.5, -10),
+            *dispatch(-9.5, -9, -8.5), ("run.wait", -8.5, 12)]
+    for t in (0, 30):
+        host += [("run.fetch", t + 12, t + 17), ("run.stream", t + 17, t + 20),
+                 (LAUNCH, t + 19.5, t + 20), *dispatch(t + 20.5, t + 21,
+                                                      t + 21.5),
+                 ("run.wait", t + 21.5, t + 42)]
+    r = reduction((-12, 75), [dev], host)
+    # host between the first and last chunk launches (-9 and 51 us):
+    # dispatch 0.5 + 1 + 0.5, fetch 5 + 5, run.stream 3 + 3, over two
+    assert values(r) == {"runner_host_us": 9.0, "runner_exposed_us": 8.0,
+                         "runner_sync_idle_us": 2.0}
+    assert harness.reader("chunk_gap_us")(r, {}) == 10.0
+
+
 def test_no_runner_spans_reads_nothing():
     chunks = [(0, 10), (20, 30)]
     r = reduction((0, 32), [device("/device:TPU:0", chunks, chunks)],

@@ -121,3 +121,28 @@ def test_roofline_by_hand(red, cell):
     spent = sum(c.dur for c in calls) / 1e9
     assert harness.reader("round_update_roofline")(red, cell) == \
         pytest.approx(100 * total / spent)
+
+
+def test_stream_reader(red, cell):
+    """stream_us_per_round reads the generator's program executions: the
+    recorded trace has no ``jit_stream_chunk``, and each of its 16-round
+    chunks is made by ``jit_make``."""
+    from chipbench.metrics import stream_us_per_round as stream
+
+    assert harness.reader("stream_us_per_round")(red, cell) is None
+    made = [(a, b) for name, a, b in red.devices[0].modules
+            if name == "jit_make"]
+    assert len(made) >= 3
+    by_hand = sum(b - a for a, b in made) / len(made) / CHUNK / 1e3
+    value = stream.per_round(red, "jit_make", CHUNK)
+    assert 0 < value == pytest.approx(by_hand)
+
+
+def test_timed_program_is_the_drivers(red):
+    """The reduction counts the program it is given as the timed one."""
+    other = trace.reduce_trace(str(DATA), program="jit_make")
+    assert [d.program for d in other.devices] == ["jit_make"]
+    made = trace.union((a, b) for name, a, b in red.devices[0].modules
+                       if name == "jit_make")
+    assert trace.chunk_spans(other.devices[0]) == made
+    assert trace.chunk_spans(red.devices[0]) != made

@@ -67,6 +67,7 @@ class Device:
     name: str
     modules: list      # [(program name, start, end)], sorted
     ops: list          # [Op], sorted by start
+    program: str = CHUNK_PROGRAM    # the timed program (the driver's)
 
 
 @dataclasses.dataclass
@@ -205,8 +206,9 @@ def find_xplane(trace_dir: str) -> str:
     return files[-1]
 
 
-def reduce_trace(path: str) -> Reduction:
-    """Read one .xplane.pb and keep what lies in the window annotation."""
+def reduce_trace(path: str, program: str = CHUNK_PROGRAM) -> Reduction:
+    """Read one .xplane.pb and keep what lies in the window annotation;
+    ``program`` is the name of the timed program (`chunk_spans`)."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -243,7 +245,7 @@ def reduce_trace(path: str) -> Reduction:
                         name, base, opcode, kind = classify(e.name)
                         ops.append(Op(name, base, opcode, kind, a, b, e.name))
         devices.append(Device(plane.name, sorted(modules, key=lambda m: m[1]),
-                              sorted(ops, key=lambda o: o.start)))
+                              sorted(ops, key=lambda o: o.start), program))
     if not devices:
         raise ValueError("the trace has no /device:TPU plane")
     return Reduction(window=window, devices=devices, host=host)
@@ -252,9 +254,9 @@ def reduce_trace(path: str) -> Reduction:
 # -- what the readers share --------------------------------------------------
 
 def chunk_spans(dev: Device) -> list:
-    """Merged spans of the chunk program's executions on one device."""
+    """Merged spans of the timed program's executions on one device."""
     return union((a, b) for name, a, b in dev.modules
-                 if name == CHUNK_PROGRAM)
+                 if name == dev.program)
 
 
 def chunk_ops(dev: Device, kinds=None) -> list:
